@@ -27,17 +27,8 @@ class CheckpointCorruptError(RuntimeError):
     """A checkpoint directory is missing, truncated, or inconsistent."""
 
 
-def _tree_flatten_with_path(tree):
-    # jax.tree.flatten_with_path only exists on newer jax; the tree_util
-    # spelling works on the 0.4.37 floor and onward.
-    fn = getattr(jax.tree, "flatten_with_path", None)
-    if fn is None:
-        fn = jax.tree_util.tree_flatten_with_path
-    return fn(tree)
-
-
 def _flatten_with_paths(tree):
-    flat, treedef = _tree_flatten_with_path(tree)
+    flat, treedef = jax.tree.flatten_with_path(tree)
     out = {}
     for path, leaf in flat:
         key = "/".join(str(getattr(p, "key", getattr(p, "idx", p)))

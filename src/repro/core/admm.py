@@ -507,14 +507,14 @@ def _step_packed(
     # x is broadcast to every neighbor: one payload per SENDER
     m_x, dx = compression.plane_compress(
         cx, lambda aid: _key_x(round_key, aid), bx,
-        agent_ids, None, x_new - u_new, like,
+        agent_ids, None, x_new - u_new, like, exchange=exchange,
     )
     x_hat_new = u_new + dx
 
     # ---- 5-6. sender-side error feedback for z (all slots at once) --------
     m_z, rec_z = compression.plane_compress(
         cz, lambda aid, nid: _key_z(round_key, aid, nid), bz,
-        aid2, nbr, state.z - state.s, like,
+        aid2, nbr, state.z - state.s, like, exchange=exchange,
     )
     z_hat_own = _masked(state.s + rec_z, mask3)
 
@@ -541,13 +541,13 @@ def _step_packed(
 
     x_hat_nbr_new = u_nbr_new + compression.plane_decompress(
         cx, lambda sid: _key_x(round_key, sid), bx,
-        nbr, None, recv_x, like, nd=2,
+        nbr, None, recv_x, like, nd=2, exchange=exchange,
     )
 
     z_hat_nbr = _masked(
         state.s_tilde + compression.plane_decompress(
             cz, lambda sid, rid: _key_z(round_key, sid, rid), bz,
-            nbr, aid2, recv_z, like, nd=2,
+            nbr, aid2, recv_z, like, nd=2, exchange=exchange,
         ),
         mask3,
     )
@@ -863,12 +863,13 @@ def _step_schedule_packed(
     m_x, rec_x = compression.plane_compress(
         cx, lambda aid, nid: _key_xe(round_key, aid, nid), bxe,
         aid2, nbr, x_new[:, None] - u_adv, like,
+        exchange=exchange,
     )
 
     # ---- 5-6. sender-side error feedback for z (gated below) --------------
     m_z, rec_z = compression.plane_compress(
         cz, lambda aid, nid: _key_z(round_key, aid, nid), bz,
-        aid2, nbr, state.z - state.s, like,
+        aid2, nbr, state.z - state.s, like, exchange=exchange,
     )
     z_hat_own = state.s + rec_z
 
@@ -930,7 +931,7 @@ def _step_schedule_packed(
 
     xhn_adv = un_adv + compression.plane_decompress(
         cx, lambda sid, rid: _key_xe(round_key, sid, rid), bxe,
-        nbr, aid2, recv_x, like, nd=2,
+        nbr, aid2, recv_x, like, nd=2, exchange=exchange,
     )
     x_hat_nbr_new = jnp.where(act, xhn_adv, xhn)
     u_nbr_new = (
@@ -939,7 +940,7 @@ def _step_schedule_packed(
 
     z_hat_nbr = state.s_tilde + compression.plane_decompress(
         cz, lambda sid, rid: _key_z(round_key, sid, rid), bz,
-        nbr, aid2, recv_z, like, nd=2,
+        nbr, aid2, recv_z, like, nd=2, exchange=exchange,
     )
 
     # ---- 8. z / s / s̃ updates on active edges only (held elsewhere) ------

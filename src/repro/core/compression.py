@@ -33,8 +33,12 @@ math).
 
 **Backend selection** is a first-class parameter: every compressor takes
 ``impl={auto,jnp,pallas}`` (``"qbit:bits=8,impl=pallas"``), resolved
-centrally through ``kernels.quantize.kernel.resolve_interpret`` — ``auto``
-means compiled Pallas on TPU and plain jnp everywhere else.  The legacy
+centrally through ``resolve_impl`` — ``auto`` means compiled Pallas on
+a TPU where the compressor's kernels compile there (the static
+``CompressorEntry.tpu_kernels`` rule: qbit, randk block/stride) and plain
+jnp everywhere else; topk and randk's uniform sampler need an
+arbitrary-index gather the TPU compiler refuses, so ``impl=pallas`` on a
+TPU raises for them instead of falling back.  The legacy
 ``kernel=true``/``false`` spec param still parses (DeprecationWarning) and
 maps to ``impl=pallas``/``jnp``.  RandK/TopK keep their seed-synchronized
 index derivation on the leaf path, so their Pallas leaf path is
@@ -45,8 +49,9 @@ unbiased).
 compress of all ``[A, S, N]`` messages goes through ``plane_compress`` /
 ``plane_decompress``.  With ``impl=pallas`` and a plane-capable compressor
 (qbit; randk block/stride) that is ONE fused Pallas launch for the whole
-plane: stochastic-rounding bits and RandK index sets are derived in-kernel
-from the counter PRNG (``kernels.prng``) seeded by (round key, sender,
+plane: stochastic-rounding bits are drawn in-kernel from the counter PRNG
+(``kernels.prng``) and RandK index sets are walked in-kernel from a
+per-message (offset, stride), all seeded by (round key, sender,
 receiver), so no random stream or index array is ever materialized in HBM —
 only the round seed is shared, exactly like the wire format.  Any other
 configuration falls back to the vmapped per-message ``compress_tree`` path,
@@ -57,7 +62,7 @@ from __future__ import annotations
 import dataclasses
 import math
 import warnings
-from collections.abc import Mapping
+from collections.abc import Callable, Mapping
 from typing import Protocol, runtime_checkable
 
 import jax
@@ -68,18 +73,28 @@ from repro.kernels import prng
 IMPLS = ("auto", "jnp", "pallas")
 
 
-def resolve_impl(impl: str) -> str:
-    """``auto`` -> backend choice (``pallas`` compiled on TPU, ``jnp``
-    elsewhere) via the same central switch the kernels use; explicit
-    ``jnp``/``pallas`` always win."""
-    if impl == "auto":
-        from repro.kernels.quantize.kernel import resolve_interpret
-
-        # resolve_interpret(None) is True off-TPU: interpret-mode Pallas
-        # is a correctness tool, not a fast path — auto stays on jnp.
-        return "jnp" if resolve_interpret(None) else "pallas"
+def resolve_impl(impl: str, tpu_kernels: bool = True) -> str:
+    """``auto`` -> backend choice: ``pallas`` (compiled) on a TPU when
+    the compressor has kernels the TPU compiler accepts
+    (``tpu_kernels``, the static rule in its ``CompressorEntry``), else
+    ``jnp``.  Explicit ``jnp`` always wins; explicit ``pallas`` runs the
+    kernels in interpret mode off-TPU and fails loudly on a TPU when
+    there are no such kernels."""
     if impl not in IMPLS:
         raise ValueError(f"impl must be one of {IMPLS}, got {impl!r}")
+    from repro.kernels import resolve_interpret
+
+    on_tpu = not resolve_interpret(None)
+    if impl == "auto":
+        # off-TPU, interpret-mode Pallas is a correctness tool, not a
+        # fast path — auto stays on jnp
+        return "pallas" if on_tpu and tpu_kernels else "jnp"
+    if impl == "pallas" and on_tpu and not tpu_kernels:
+        raise ValueError(
+            "impl=pallas: this compressor configuration has no kernel the "
+            "TPU compiler accepts (arbitrary-index gather/scatter); use "
+            "impl=auto or impl=jnp"
+        )
     return impl
 
 
@@ -165,6 +180,14 @@ class Compressor(Protocol):
     def wire_bytes(self, shape, dtype) -> int: ...
 
 
+def resolved_impl(comp) -> str:
+    """The backend ``comp`` runs on here: ``resolve_impl`` under its
+    registered TPU rule."""
+    return resolve_impl(
+        comp.impl, COMPRESSORS[comp.name].tpu_kernels(comp)
+    )
+
+
 def _check_impl(impl: str):
     if impl not in IMPLS:
         raise ValueError(f"impl must be one of {IMPLS}, got {impl!r}")
@@ -215,8 +238,9 @@ class BBitQuantizer:
     ``repro.kernels.quantize`` — on the packed plane the whole ``[A,S,N]``
     compress is ONE launch with in-kernel counter-PRNG rounding bits.
     Same quantizer family and wire format; the stochastic-rounding stream
-    differs from the jnp path (raw uint32 bits vs ``jax.random.uniform``),
-    so the Pallas path is unbiased and contractive but not bit-identical.
+    differs from the jnp path (counter-PRNG bits vs
+    ``jax.random.uniform``), so the Pallas path is unbiased and
+    contractive but not bit-identical.
     """
 
     bits: int = 8
@@ -236,7 +260,7 @@ class BBitQuantizer:
         return 2 ** (self.bits - 1) - 1
 
     def _pallas(self) -> bool:
-        return resolve_impl(self.impl) == "pallas"
+        return resolved_impl(self) == "pallas"
 
     def compress(self, key, x) -> Payload:
         if self._pallas():
@@ -355,13 +379,23 @@ class RandK:
             )
 
     def _pallas(self) -> bool:
-        return resolve_impl(self.impl) == "pallas"
+        return resolved_impl(self) == "pallas"
 
     def _k(self, n: int) -> int:
         return max(1, int(round(self.fraction * n)))
 
     def _offset(self, key, n: int):
         return jax.random.randint(key, (), 0, n)
+
+    def _affine(self, key, n: int):
+        """``(off [1], stride [1])`` of the block/stride index set."""
+        if self.sampler == "block":
+            return (jnp.reshape(self._offset(key, n), (1,)),
+                    jnp.ones((1,), jnp.int32))
+        off, stride = prng.affine_params(
+            prng.key_seed(key), n, prng.coprime_strides(n)
+        )
+        return jnp.reshape(off, (1,)), jnp.reshape(stride, (1,))
 
     def _indices(self, key, n: int):
         k = self._k(n)
@@ -380,11 +414,12 @@ class RandK:
         if self._pallas():
             from repro.kernels.sparse_gather import ops as sg
 
-            if self.sampler == "block":  # fused dynamic-slice window
-                return Payload(v=sg.cyclic_gather(
-                    xf, self._offset(key, n), self._k(n)
-                ))
-            return Payload(v=sg.sparse_gather(xf, self._indices(key, n)))
+            if self.sampler == "uniform":
+                return Payload(v=sg.sparse_gather(xf, self._indices(key, n)))
+            off, stride = self._affine(key, n)
+            return Payload(v=sg.randk_gather(
+                xf[None], off, stride, self._k(n)
+            )[0])
         return Payload(v=jnp.take(xf, self._indices(key, n), axis=0))
 
     def decompress(self, key, payload, like) -> jax.Array:
@@ -393,14 +428,15 @@ class RandK:
         if self._pallas():
             from repro.kernels.sparse_gather import ops as sg
 
-            if self.sampler == "block":
-                out = sg.cyclic_scatter(
-                    payload["v"], self._offset(key, n), n, gain=n / k
-                )
-            else:
+            if self.sampler == "uniform":
                 out = sg.sparse_scatter(
                     payload["v"], self._indices(key, n), n, gain=n / k
                 )
+            else:
+                off, stride = self._affine(key, n)
+                out = sg.randk_scatter(
+                    payload["v"][None], off, stride, n, n / k
+                )[0]
             return jnp.reshape(out, like.shape).astype(like.dtype)
         idx = self._indices(key, n)
         out = jnp.zeros((n,), payload["v"].dtype)
@@ -462,7 +498,7 @@ class TopK:
         _check_impl(self.impl)
 
     def _pallas(self) -> bool:
-        return resolve_impl(self.impl) == "pallas"
+        return resolved_impl(self) == "pallas"
 
     def _k(self, n: int) -> int:
         return max(1, int(round(self.fraction * n)))
@@ -542,11 +578,7 @@ def tree_wire_bytes(comp, tree) -> int:
 
 def _use_fused(comp) -> bool:
     ready = getattr(comp, "plane_ready", None)
-    return (
-        ready is not None
-        and ready()
-        and resolve_impl(comp.impl) == "pallas"
-    )
+    return ready is not None and ready() and resolved_impl(comp) == "pallas"
 
 
 def _vmap_n(fn, nd: int):
@@ -555,23 +587,47 @@ def _vmap_n(fn, nd: int):
     return fn
 
 
-def plane_compress(comp, keyfn, base_key, senders, receivers, delta, like):
+def _per_agent_shard(fn, exchange, seed, *planes):
+    """``fn(seed, *planes)`` — inside a shard_map over the agent axis when
+    ``exchange`` is bound to a mesh.  The compiler cannot partition a
+    Pallas kernel, and every message is independent, so each device runs
+    the fused kernels on its own agents' rows (``planes`` all lead with
+    the agent dim; the seed pair is replicated)."""
+    if exchange is None or exchange.axis is None:
+        return fn(seed, *planes)
+    from jax.sharding import PartitionSpec as P
+
+    return jax.shard_map(
+        fn, mesh=exchange.mesh,
+        in_specs=(P(),) + (P(exchange.axis),) * len(planes),
+        out_specs=P(exchange.axis),
+        check_vma=False,  # pallas_call outputs carry no varying-axes type
+    )(seed, *planes)
+
+
+def plane_compress(comp, keyfn, base_key, senders, receivers, delta, like,
+                   exchange=None):
     """Compress every message of a batched plane ``delta [..., N]`` and
     return ``(payload_tree, reconstruction)`` (the reconstruction feeds
     error feedback — both endpoints must see the SAME decompress).
 
     Fused route (``impl=pallas`` + plane-capable compressor): ONE Pallas
-    launch for the whole plane, per-message randomness derived in-kernel
-    from ``(key_seed(base_key), sender, receiver)`` — ``receivers=None``
-    marks one-to-all broadcast messages.  Otherwise: the exact vmapped
-    per-message ``compress_tree(comp, keyfn(ids...), ...)`` path the tree
-    solvers use, bit-identical to pre-plane behavior.
+    launch for the whole plane (per device, when ``exchange`` is bound to
+    a mesh), per-message randomness derived from ``(key_seed(base_key),
+    sender, receiver)`` — ``receivers=None`` marks one-to-all broadcast
+    messages.  Otherwise: the exact vmapped per-message
+    ``compress_tree(comp, keyfn(ids...), ...)`` path the tree solvers
+    use, bit-identical to pre-plane behavior.
     """
     if _use_fused(comp):
-        seed = prng.key_seed(base_key)
-        p = comp.compress_plane(seed, senders, receivers, delta)
-        rec = comp.decompress_plane(seed, senders, receivers, p, like)
-        return p, rec
+        def fused(seed, senders, delta, *receivers):
+            rids = receivers[0] if receivers else None
+            p = comp.compress_plane(seed, senders, rids, delta)
+            return p, comp.decompress_plane(seed, senders, rids, p, like)
+
+        extra = () if receivers is None else (receivers,)
+        return _per_agent_shard(fused, exchange, prng.key_seed(base_key),
+                                senders, delta, *extra)
     nd = delta.ndim - 1
 
     if receivers is None:
@@ -591,14 +647,19 @@ def plane_compress(comp, keyfn, base_key, senders, receivers, delta, like):
 
 
 def plane_decompress(comp, keyfn, base_key, senders, receivers, payload,
-                     like, nd: int):
+                     like, nd: int, exchange=None):
     """Receiver-side reconstruction of a batched payload plane —
     re-derives the SAME per-message randomness as ``plane_compress`` (the
     seeded wire format: only ``base_key`` round state is shared).  ``nd``
     is the number of batch dims on the payload leaves."""
     if _use_fused(comp):
-        seed = prng.key_seed(base_key)
-        return comp.decompress_plane(seed, senders, receivers, payload, like)
+        def fused(seed, senders, payload, *receivers):
+            rids = receivers[0] if receivers else None
+            return comp.decompress_plane(seed, senders, rids, payload, like)
+
+        extra = () if receivers is None else (receivers,)
+        return _per_agent_shard(fused, exchange, prng.key_seed(base_key),
+                                senders, payload, *extra)
 
     if receivers is None:
         def one(s, p):
@@ -701,24 +762,32 @@ class CompressorEntry:
     cls: type
     params: frozenset
     doc: str = ""
+    # static rule: does this configuration have Pallas kernels the TPU
+    # compiler accepts?  ``impl=auto`` picks them on a TPU exactly when
+    # it holds; explicit ``impl=pallas`` on a TPU fails when it does not
+    tpu_kernels: Callable[[Compressor], bool] = lambda comp: False
 
 
-def _entry(cls, doc: str) -> CompressorEntry:
+def _entry(cls, doc: str, tpu_kernels=lambda comp: False) -> CompressorEntry:
     name = cls.__dataclass_fields__["name"].default
     params = frozenset(
         f.name
         for f in dataclasses.fields(cls)
         if f.init and f.name not in ("name", "unbiased")
     )
-    return CompressorEntry(name=name, cls=cls, params=params, doc=doc)
+    return CompressorEntry(name=name, cls=cls, params=params, doc=doc,
+                           tpu_kernels=tpu_kernels)
 
 
 COMPRESSORS: dict[str, CompressorEntry] = {
     e.name: e
     for e in (
         _entry(Identity, "no compression (exact LT-ADMM)"),
-        _entry(BBitQuantizer, "unbiased stochastic b-bit quantizer (C1)"),
-        _entry(RandK, "seed-synchronized rand-k, zero index bytes (C2)"),
+        _entry(BBitQuantizer, "unbiased stochastic b-bit quantizer (C1)",
+               tpu_kernels=lambda comp: True),
+        # uniform needs an arbitrary-index gather, which does not lower
+        _entry(RandK, "seed-synchronized rand-k, zero index bytes (C2)",
+               tpu_kernels=lambda comp: comp.sampler in ("block", "stride")),
         _entry(TopK, "biased magnitude top-k (values + indices, needs EF)"),
     )
 }

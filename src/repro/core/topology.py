@@ -34,14 +34,11 @@ The ``Exchange`` primitive has two implementations with identical
 semantics (bit-identical results — masked slots deliver the agent's own
 message on both paths):
 
-* ``axis=None`` — gather-by-index (``jnp.take``) on the leading agent
-  axis.  Used for host simulation/tests.
+* ``axis=None`` — gather-by-index on the leading agent axis (static
+  row slices: the routing tables are host constants).  Used for host simulation/tests.
 * ``axis=<mesh axis>`` — ``shard_map`` over the agent mesh axis with one
   ``lax.ppermute`` per slot; every other mesh axis is left to the
   compiler.  This is the wire traffic the roofline counts.
-
-jax-version floor: works on jax >= 0.4.37 (falls back to
-``jax.experimental.shard_map`` when ``jax.shard_map`` is absent).
 """
 from __future__ import annotations
 
@@ -435,8 +432,19 @@ def make_topology(spec: str, n_agents: int):
 # ---------------------------------------------------------------------------
 
 
+def _take_rows(x, src_ids):
+    """``x[src_ids]`` on axis 0 for a host-constant index array, as one
+    static slice per row.  A gather would do the same, but XLA's TPU
+    compiler splits a gather of rows this wide (a packed parameter plane)
+    into 32K-element pieces, and its compile time then grows with the
+    plane width."""
+    src = np.asarray(src_ids)
+    rows = [x[int(i)] for i in src.reshape(-1)]
+    return jnp.stack(rows).reshape(src.shape + x.shape[1:])
+
+
 def _take_tree(tree, src_ids):
-    return jax.tree.map(lambda x: jnp.take(x, src_ids, axis=0), tree)
+    return jax.tree.map(lambda x: _take_rows(x, src_ids), tree)
 
 
 def _ppermute_tree(tree, axis_name, perm):
@@ -446,24 +454,11 @@ def _ppermute_tree(tree, axis_name, perm):
 
 
 def _shard_map(fn, mesh, axis):
-    """jax.shard_map when available, jax.experimental fallback otherwise
-    (jax < 0.5 — the installed floor is 0.4.37).
-
-    The modern path leaves every non-agent mesh axis to the compiler
-    (``axis_names={axis}``); the 0.4.x fallback has no working partial-auto
-    mode, so it goes fully manual with ``P(axis)`` specs — semantically
-    identical, at the cost of replicating the message over the other axes
-    inside the body."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            fn, mesh=mesh, in_specs=P(axis), out_specs=P(axis),
-            axis_names={axis},
-        )
-    from jax.experimental.shard_map import shard_map
-
-    return shard_map(
+    """Manual over the agent axis only: every non-agent mesh axis is
+    left to the compiler."""
+    return jax.shard_map(
         fn, mesh=mesh, in_specs=P(axis), out_specs=P(axis),
-        check_rep=False,
+        axis_names={axis},
     )
 
 
@@ -474,6 +469,9 @@ class Exchange:
 
     ``axis``: mesh axis name the agent dim is sharded over, or None for the
     pure-jnp gather implementation (host simulation / tiny tests).
+    ``gather=True`` keeps the gather routing on a mesh-bound exchange: the
+    host-simulated exchange on an agent-sharded placement, which the
+    ppermute path is checked against.
 
     Masked slots deliver the agent's OWN message (a self-loop) on both
     implementations, so the two paths are bit-identical everywhere; the
@@ -490,6 +488,7 @@ class Exchange:
     axis: str | None = None
     mesh: Any = None  # jax.sharding.Mesh when axis is not None
     faults: Any = None  # core.faults.FaultPlane | None
+    gather: bool = False
 
     def gather_from_neighbors(self, per_agent_tree):
         """Every agent broadcasts one message; returns tuple over slots of
@@ -520,7 +519,7 @@ class Exchange:
     #
     # Same semantics as the tuple-of-slots methods above, but the slot
     # axis rides INSIDE the arrays (``[A, S, ...]``), so the host path is
-    # one gather for all slots and the mesh path runs its per-slot
+    # one routing op for all slots and the mesh path runs its per-slot
     # ppermutes inside a single shard_map (one program, S collectives).
 
     def gather_batched(self, per_agent_tree, round_index=None):
@@ -528,11 +527,8 @@ class Exchange:
         ``[A, S, ...]`` out with ``out[i, s] = in[neighbor_table()[i, s]]``
         (own message on masked slots, as always)."""
         nbr = self.topo.neighbor_table()
-        if self.axis is None:
-            idx = jnp.asarray(nbr)  # [A, S]
-            out = jax.tree.map(
-                lambda x: jnp.take(x, idx, axis=0), per_agent_tree
-            )
+        if self.axis is None or self.gather:
+            out = _take_tree(per_agent_tree, nbr)  # [A, S, ...]
             return self._maybe_inject(out, round_index)
         A, S = self.topo.n_agents, self.topo.n_slots
         perms = [
@@ -556,14 +552,14 @@ class Exchange:
         nbr = self.topo.neighbor_table()
         A, S = self.topo.n_agents, self.topo.n_slots
         rev = self.topo.reverse_slot
-        if self.axis is None:
-            flat_idx = jnp.asarray(
+        if self.axis is None or self.gather:
+            flat_idx = (
                 nbr * S + np.asarray(rev, dtype=nbr.dtype)[None, :]
             )  # [A, S]: sender agent * S + sender slot
 
             def route(x):
                 x2 = jnp.reshape(x, (A * S,) + x.shape[2:])
-                return jnp.take(x2, flat_idx, axis=0)
+                return _take_rows(x2, flat_idx)
 
             return self._maybe_inject(
                 jax.tree.map(route, edge_tree), round_index)
@@ -595,8 +591,8 @@ class Exchange:
     def _route(self, tree, src_ids):
         """recv[i] = sent[src_ids[i]] — src_ids must be a partial
         permutation extended with self-loops (Topology invariant)."""
-        if self.axis is None:
-            return _take_tree(tree, np.asarray(src_ids))
+        if self.axis is None or self.gather:
+            return _take_tree(tree, src_ids)
         perm = [(int(src_ids[i]), i) for i in range(self.topo.n_agents)]
         fn = partial(_ppermute_tree, axis_name=self.axis, perm=perm)
         return _shard_map(fn, self.mesh, self.axis)(tree)

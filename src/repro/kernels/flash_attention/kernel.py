@@ -22,6 +22,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import resolve_interpret
+
 DEFAULT_Q_BLOCK = 128
 DEFAULT_KV_BLOCK = 128
 _NEG_INF = -1e30
@@ -83,9 +85,11 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
 )
 def flash_attention(q, k, v, *, causal=True, window=None,
                     q_block=DEFAULT_Q_BLOCK, kv_block=DEFAULT_KV_BLOCK,
-                    interpret=True):
+                    interpret=None):
     """q [B,H,T,Dh]; k,v [B,KH,S,Dh] -> [B,H,T,Dh].  T % q_block == 0;
-    S is padded to kv_block internally (masked)."""
+    S is padded to kv_block internally (masked).  ``interpret=None``:
+    compiled on TPU, interpret mode elsewhere."""
+    interpret = resolve_interpret(interpret)
     b, h, t, dh = q.shape
     kh, s_len = k.shape[1], k.shape[2]
     g = h // kh

@@ -21,7 +21,7 @@ def supported(q, k, v, mask) -> bool:
 
 
 def flash_attention(q, k, v, mask=None, *, causal=True, window=None,
-                    interpret=True):
+                    interpret=None):
     """q [B,T,H,Dh]; k,v [B,S,KH,Dh] -> [B,T,H,Dh]."""
     del mask
     qt = jnp.swapaxes(q, 1, 2)

@@ -53,8 +53,14 @@ _ROTATIONS = ((13, 15, 26, 6), (17, 29, 16, 24))
 # a peer id so broadcast and per-edge streams never collide
 BROADCAST = np.uint32(0xFFFFFFFF)
 
+# largest message length the affine index family supports: every
+# intermediate of ``mulmod`` and of the kernels' index walk stays int32
+MAX_N = 2**30
+
 
 def _u32(x):
+    if isinstance(x, int):  # a Python int in [2^31, 2^32) overflows int32
+        return jnp.asarray(np.uint32(x))
     return jnp.asarray(x).astype(jnp.uint32)
 
 
@@ -112,8 +118,13 @@ def random_bits(seed, ctr, stream=0):
 
 
 def uniform01(bits):
-    """uint32 bits -> f32 in [0, 1) (the stochastic-rounding kappa)."""
-    return bits.astype(jnp.float32) * np.float32(2.0**-32)
+    """uint32 bits -> f32 in [0, 1 - 2^-24] (the stochastic-rounding
+    kappa): the top 24 bits, exact in f32.  Goes through int32 because
+    the TPU compiler has no uint32 -> float32 cast, and a full 32-bit
+    value would round up to exactly 1.0 for the top 2^7 bit patterns."""
+    return (bits >> np.uint32(8)).astype(jnp.int32).astype(jnp.float32) * (
+        np.float32(2.0**-24)
+    )
 
 
 def derive_offset(seed, n: int):
@@ -150,14 +161,40 @@ def coprime_strides(n: int, size: int = 64) -> tuple:
     return tuple(out)
 
 
-def affine_indices(seed, n: int, k: int, strides: tuple):
-    """The seeded affine index set ``(off + j * stride) % n`` for
-    ``j < k`` — duplicate-free (stride coprime to n, k <= n), exact-k,
-    never materialized by the fused kernels (each tile computes its own
-    ``j`` range in-register; THIS function is the jnp oracle)."""
+def mulmod(a, b, n: int):
+    """``(a * b) % n`` elementwise without 32-bit overflow, for int32
+    ``0 <= a, b < n <= 2^30``: double-and-add over the bits of ``a``,
+    every partial sum below 2^31.  Plain jnp, so it runs in kernel
+    bodies (scalars) and in the oracles (vectors) alike."""
+    assert 1 <= n <= MAX_N, n
+    n = np.int32(n)
+    r = jnp.zeros(jnp.broadcast_shapes(jnp.shape(a), jnp.shape(b)), jnp.int32)
+    for bit in reversed(range(int(n).bit_length())):
+        r = r + r
+        r = jnp.where(r >= n, r - n, r)
+        t = r + b
+        t = jnp.where(t >= n, t - n, t)
+        r = jnp.where(((a >> bit) & 1) == 1, t, r)
+    return r
+
+
+def affine_params(seed, n: int, strides: tuple):
+    """The seeded ``(offset, stride)`` of the affine index family (any
+    seed shape: one pair per message)."""
     off = derive_offset(seed, n)
     stride = jnp.asarray(strides, jnp.int32)[
         derive_stride_slot(seed, len(strides))
     ]
+    return off, stride
+
+
+def affine_indices(seed, n: int, k: int, strides: tuple):
+    """The seeded affine index set ``(off + j * stride) % n`` for
+    ``j < k`` — duplicate-free (stride coprime to n, k <= n), exact-k,
+    never materialized by the fused kernels (each tile walks its own
+    ``j`` range; THIS function is the jnp oracle).  ``j * stride`` is
+    reduced exactly (``mulmod``), so the set stays duplicate-free for
+    every n up to ``MAX_N``."""
+    off, stride = affine_params(seed, n, strides)
     j = jnp.arange(k, dtype=jnp.int32)
-    return (off + j * stride) % np.int32(n)
+    return (off + mulmod(j, stride, n)) % np.int32(n)

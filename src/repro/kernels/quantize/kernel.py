@@ -1,19 +1,23 @@
-"""Pallas TPU kernel: blockwise stochastic b-bit quantization (paper's C1).
+"""Pallas TPU kernel: fused stochastic b-bit quantization (paper's C1).
 
-This is the compression hot spot of LT-ADMM-CC: every outer round each agent
-quantizes 2·|N_i| parameter-sized tensors (x- and z-messages).  The kernel
-streams the tensor through VMEM in (BLOCK,) tiles, quantizes against a
-precomputed global inf-norm scale, and emits the int8 wire format (b=8) or
-nibble-packed uint8 (b=4) — the dequantize kernel reverses it.
+This is the compression hot spot of LT-ADMM-CC: every outer round each
+agent quantizes its x- and z-messages.  ``quantize_rows`` does a whole
+batch of M messages in ONE launch: the stochastic-rounding kappas are
+drawn in-kernel from the counter PRNG (``repro.kernels.prng``) under a
+per-message seed pair, so no random stream is ever materialized in HBM.
 
-TPU adaptation notes:
-* the inf-norm reduction is a separate cheap pass (jnp.max |x|) so the kernel
-  is a single-sweep elementwise pipeline — memory-bound by design, reading
-  f32 and writing b/8 bytes per element;
-* stochastic rounding bits arrive as a uint32 input stream.  On real TPU
-  this would use pltpu.prng_random_bits to avoid the extra HBM read; the
-  input-stream variant is used here because it is exactly reproducible in
-  interpret mode on CPU (validated against ref.py).
+TPU layout:
+* each message is viewed as ``[rows, L]`` lane-dense rows (L = 128 for
+  b=8, 256 for b=4 so one input row packs into one 128-byte output row)
+  and streamed in ``(TB, L)`` tiles, TB a multiple of 32: the f32 input
+  sits on the (8, 128) tiling and the int8/uint8 output on (32, 128);
+* the per-message seed pair and inf-norm multiplier are scalar-prefetch
+  operands (SMEM), read once per grid step on the scalar unit;
+* the inf-norm reduction is a separate cheap jnp pass, so the kernel is
+  a single elementwise sweep (read f32, write b/8 bytes per element).
+
+Dequantization is elementwise and PRNG-free; it stays plain jnp
+(``ops.dequantize_plane``), which XLA fuses into its consumer.
 """
 from __future__ import annotations
 
@@ -21,199 +25,94 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-BLOCK = 1024  # elements per VMEM tile (multiple of 128 lanes)
+from repro.kernels import prng, resolve_interpret
 
-
-def resolve_interpret(interpret):
-    """``None`` -> auto by backend: compiled on TPU (where the Mosaic
-    pipeline exists), interpret everywhere else (CPU tests/CI).  Explicit
-    True/False always wins."""
-    if interpret is None:
-        return jax.default_backend() != "tpu"
-    return bool(interpret)
+LANES = 128
+ROW_ALIGN = 32  # int8/uint8 tiling is (32, 128)
+MAX_TILE_ROWS = 256  # (256, 128) f32 = 128 KiB per input tile
 
 
-def _quantize8_kernel(x_ref, rnd_ref, scale_ref, q_ref, *, levels):
-    x = x_ref[...].astype(jnp.float32)
-    scale = scale_ref[0]
-    # kappa in [0, 1) from uint32 bits
-    kappa = rnd_ref[...].astype(jnp.float32) * (1.0 / 4294967296.0)
-    y = levels * jnp.abs(x) / scale + kappa
-    q = jnp.sign(x) * jnp.floor(y)
-    q_ref[...] = q.astype(jnp.int8)
+def lane_width(bits: int) -> int:
+    """Elements per kernel row: b=4 packs a 256-element row into one
+    128-byte output row."""
+    return LANES if bits == 8 else 2 * LANES
 
 
-def _dequantize8_kernel(q_ref, scale_ref, x_ref, *, levels):
-    q = q_ref[...].astype(jnp.float32)
-    x_ref[...] = (scale_ref[0] * q / levels).astype(x_ref.dtype)
+def tile_rows(rows: int) -> int:
+    """Rows per grid step for a message of ``rows`` kernel rows: the
+    whole (32-aligned) message when small, else MAX_TILE_ROWS."""
+    return min(MAX_TILE_ROWS, -(-rows // ROW_ALIGN) * ROW_ALIGN)
 
 
-def _quantize4_kernel(x_ref, rnd_ref, scale_ref, q_ref, *, levels):
-    x = x_ref[...].astype(jnp.float32)
-    scale = scale_ref[0]
-    kappa = rnd_ref[...].astype(jnp.float32) * (1.0 / 4294967296.0)
-    q = jnp.sign(x) * jnp.floor(levels * jnp.abs(x) / scale + kappa)
-    q = q.astype(jnp.int32) + 8  # offset-8 nibbles in [1, 15]
-    hi = q[0::2]
-    lo = q[1::2]
-    q_ref[...] = ((hi << 4) | lo).astype(jnp.uint8)
-
-
-def _dequantize4_kernel(q_ref, scale_ref, x_ref, *, levels):
-    p = q_ref[...].astype(jnp.int32)
-    hi = ((p >> 4) & 0xF) - 8
-    lo = (p & 0xF) - 8
-    q = jnp.stack([hi, lo], axis=1).reshape(-1).astype(jnp.float32)
-    x_ref[...] = (scale_ref[0] * q / levels).astype(x_ref.dtype)
-
-
-@functools.partial(jax.jit, static_argnames=("bits", "interpret"))
-def quantize(x_flat, rnd_bits, scale, *, bits=8, interpret=None):
-    """x_flat [n] f32 (n % BLOCK == 0), rnd_bits [n] uint32, scale scalar.
-
-    Returns int8 [n] (b=8) or uint8 [n//2] (b=4).  ``interpret=None``
-    auto-selects by backend (compiled on TPU, interpret elsewhere).
-    """
-    interpret = resolve_interpret(interpret)
-    n = x_flat.shape[0]
-    assert n % BLOCK == 0, n
-    levels = float(2 ** (bits - 1) - 1)
-    grid = (n // BLOCK,)
-    scale = jnp.reshape(scale, (1,))
-    if bits == 8:
-        return pl.pallas_call(
-            functools.partial(_quantize8_kernel, levels=levels),
-            grid=grid,
-            in_specs=[
-                pl.BlockSpec((BLOCK,), lambda i: (i,)),
-                pl.BlockSpec((BLOCK,), lambda i: (i,)),
-                pl.BlockSpec((1,), lambda i: (0,)),
-            ],
-            out_specs=pl.BlockSpec((BLOCK,), lambda i: (i,)),
-            out_shape=jax.ShapeDtypeStruct((n,), jnp.int8),
-            interpret=interpret,
-        )(x_flat, rnd_bits, scale)
-    if bits == 4:
-        return pl.pallas_call(
-            functools.partial(_quantize4_kernel, levels=levels),
-            grid=grid,
-            in_specs=[
-                pl.BlockSpec((BLOCK,), lambda i: (i,)),
-                pl.BlockSpec((BLOCK,), lambda i: (i,)),
-                pl.BlockSpec((1,), lambda i: (0,)),
-            ],
-            out_specs=pl.BlockSpec((BLOCK // 2,), lambda i: (i,)),
-            out_shape=jax.ShapeDtypeStruct((n // 2,), jnp.uint8),
-            interpret=interpret,
-        )(x_flat, rnd_bits, scale)
-    raise ValueError(bits)
-
-
-# ---------------------------------------------------------------------------
-# Fused plane quantize: [M, n] messages, ONE launch, in-kernel PRNG
-# ---------------------------------------------------------------------------
-
-
-def _plane_counter(tile):
-    """Global element counter for grid position (row-local): the kappa
-    stream restarts per message, so sender and receiver only need the
-    per-message seed to agree on every rounding decision."""
-    i = pl.program_id(1)
-    j = jax.lax.broadcasted_iota(jnp.int32, (1, tile), 1) + i * tile
-    return j.astype(jnp.uint32)
-
-
-def _quantize_plane_kernel(seed_ref, sid_ref, rid_ref, scale_ref, x_ref,
-                           q_ref, *, levels, bits):
-    from repro.kernels import prng
-
-    es = prng.fold(
-        (seed_ref[0], seed_ref[1]), sid_ref[0], rid_ref[0]
+def pack_nibbles(qi):
+    """``[TB, 256]`` int32 offset-8 nibbles -> ``[TB, 128]`` bytes with
+    byte c = (q[2c] << 4) | q[2c + 1] — the flat wire order.  Strided
+    lane slices do not lower on TPU, so each 128-lane half is compacted
+    with an in-vreg lane gather and the halves are selected by lane."""
+    tb = qi.shape[0]
+    lane = jax.lax.broadcasted_iota(jnp.int32, (tb, LANES), 1)
+    even = (2 * lane) % LANES
+    halves = (qi[:, :LANES], qi[:, LANES:])
+    hi = [jnp.take_along_axis(h, even, axis=1) for h in halves]
+    lo = [jnp.take_along_axis(h, even + 1, axis=1) for h in halves]
+    first = lane < LANES // 2
+    return (jnp.where(first, hi[0], hi[1]) << 4) | jnp.where(
+        first, lo[0], lo[1]
     )
-    kappa = prng.uniform01(prng.random_bits(es, _plane_counter(BLOCK)))
+
+
+def _quantize_rows_kernel(s0_ref, s1_ref, mult_ref, x_ref, q_ref, *,
+                          levels, bits):
+    m, i = pl.program_id(0), pl.program_id(1)
+    tb, width = x_ref.shape
+    row = jax.lax.broadcasted_iota(jnp.int32, (tb, width), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (tb, width), 1)
+    ctr = ((i * tb + row) * width + col).astype(jnp.uint32)
+    kappa = prng.uniform01(prng.random_bits((s0_ref[m], s1_ref[m]), ctr))
     x = x_ref[...].astype(jnp.float32)
-    q = jnp.sign(x) * jnp.floor(levels * jnp.abs(x) / scale_ref[0] + kappa)
+    q = jnp.minimum(jnp.floor(jnp.abs(x) * mult_ref[m] + kappa), levels)
+    q = jnp.sign(x) * q
     if bits == 8:
         q_ref[...] = q.astype(jnp.int8)
     else:
-        qi = q.astype(jnp.int32) + 8  # offset-8 nibbles in [1, 15]
-        hi = qi[:, 0::2]
-        lo = qi[:, 1::2]
-        q_ref[...] = ((hi << 4) | lo).astype(jnp.uint8)
+        q_ref[...] = pack_nibbles(q.astype(jnp.int32) + 8).astype(jnp.uint8)
 
 
 @functools.partial(jax.jit, static_argnames=("bits", "interpret"))
-def quantize_plane(seed, sids, rids, x, scale, *, bits=8, interpret=None):
-    """Fused quantization of a whole message plane: ONE pallas launch.
+def quantize_rows(s0, s1, mult, x, *, bits=8, interpret=None):
+    """Quantize M messages in ONE launch.
 
-    ``x [M, n]`` f32 (n % BLOCK == 0) holds M gathered messages (the
-    slot-batched ``[A, S, N]`` plane flattened to rows); ``sids``/``rids``
-    [M] uint32 are the per-message (sender, receiver) ids and ``seed``
-    is the round's ``(u32, u32)`` pair — the stochastic-rounding kappas
-    are derived in-kernel from (seed, sender, receiver, element), so no
-    random stream is ever materialized in HBM (the vmapped leaf path
-    reads a precomputed ``jax.random.bits`` array per message).
-    ``scale [M]`` is the per-message inf-norm from the cheap jnp pass.
+    ``x [M, R, L]`` f32 is each message as ``R`` rows of ``L =
+    lane_width(bits)`` elements (R a multiple of ``tile_rows(R)``);
+    ``s0``/``s1`` [M] uint32 are the per-message seed pairs and ``mult``
+    [M] f32 is ``levels / ||x_m||_inf``.  Element e of message m rounds
+    with kappa from ``prng.random_bits((s0[m], s1[m]), e)``.  Returns
+    ``[M, R, 128]``: int8 (b=8) or nibble-packed uint8 (b=4).
     """
     interpret = resolve_interpret(interpret)
-    m, n = x.shape
-    assert n % BLOCK == 0, n
-    levels = float(2 ** (bits - 1) - 1)
-    grid = (m, n // BLOCK)
-    out_block = BLOCK if bits == 8 else BLOCK // 2
-    out_n = n if bits == 8 else n // 2
+    m, rows, width = x.shape
+    assert width == lane_width(bits), (width, bits)
+    tb = tile_rows(rows)
+    assert rows % tb == 0, (rows, tb)
+    levels = np.float32(2 ** (bits - 1) - 1)
     return pl.pallas_call(
-        functools.partial(_quantize_plane_kernel, levels=levels, bits=bits),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((2,), lambda m_, i: (0,)),
-            pl.BlockSpec((1,), lambda m_, i: (m_,)),
-            pl.BlockSpec((1,), lambda m_, i: (m_,)),
-            pl.BlockSpec((1,), lambda m_, i: (m_,)),
-            pl.BlockSpec((1, BLOCK), lambda m_, i: (m_, i)),
-        ],
-        out_specs=pl.BlockSpec((1, out_block), lambda m_, i: (m_, i)),
+        functools.partial(_quantize_rows_kernel, levels=levels, bits=bits),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(m, rows // tb),
+            in_specs=[
+                pl.BlockSpec((None, tb, width), lambda m_, i, *_: (m_, i, 0))
+            ],
+            out_specs=pl.BlockSpec(
+                (None, tb, LANES), lambda m_, i, *_: (m_, i, 0)
+            ),
+        ),
         out_shape=jax.ShapeDtypeStruct(
-            (m, out_n), jnp.int8 if bits == 8 else jnp.uint8
+            (m, rows, LANES), jnp.int8 if bits == 8 else jnp.uint8
         ),
         interpret=interpret,
-    )(jnp.stack(seed), sids, rids, scale, x)
-
-
-@functools.partial(
-    jax.jit, static_argnames=("bits", "n", "out_dtype", "interpret")
-)
-def dequantize(q, scale, *, bits=8, n=None, out_dtype=jnp.float32,
-               interpret=None):
-    interpret = resolve_interpret(interpret)
-    levels = float(2 ** (bits - 1) - 1)
-    scale = jnp.reshape(scale, (1,))
-    if bits == 8:
-        n = n or q.shape[0]
-        return pl.pallas_call(
-            functools.partial(_dequantize8_kernel, levels=levels),
-            grid=(n // BLOCK,),
-            in_specs=[
-                pl.BlockSpec((BLOCK,), lambda i: (i,)),
-                pl.BlockSpec((1,), lambda i: (0,)),
-            ],
-            out_specs=pl.BlockSpec((BLOCK,), lambda i: (i,)),
-            out_shape=jax.ShapeDtypeStruct((n,), out_dtype),
-            interpret=interpret,
-        )(q, scale)
-    if bits == 4:
-        n = n or q.shape[0] * 2
-        return pl.pallas_call(
-            functools.partial(_dequantize4_kernel, levels=levels),
-            grid=(n // BLOCK,),
-            in_specs=[
-                pl.BlockSpec((BLOCK // 2,), lambda i: (i,)),
-                pl.BlockSpec((1,), lambda i: (0,)),
-            ],
-            out_specs=pl.BlockSpec((BLOCK,), lambda i: (i,)),
-            out_shape=jax.ShapeDtypeStruct((n,), out_dtype),
-            interpret=interpret,
-        )(q, scale)
-    raise ValueError(bits)
+    )(s0, s1, mult, x)

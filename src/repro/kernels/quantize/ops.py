@@ -1,38 +1,54 @@
-"""Jitted public wrapper: quantize/dequantize arbitrary-shape tensors.
+"""Jitted public wrappers: quantize/dequantize message planes and tensors.
 
-Handles padding to the kernel BLOCK, the inf-norm scale pass, and the
-PRNG-bit stream; exposes the same (compress, decompress) contract as
-``repro.core.compression.BBitQuantizer`` so the trainer can swap the Pallas
-path in with ``impl=pallas`` (or leave ``impl=auto`` to pick it up
-wherever Pallas lowering is available).
+Handles the per-message seeds, the inf-norm scale pass, padding and the
+lane-dense ``[M, rows, L]`` view the kernel streams; exposes the
+(compress, decompress) pieces ``repro.core.compression.BBitQuantizer``
+uses with ``impl=pallas`` (or ``impl=auto`` on a TPU).
 """
 from __future__ import annotations
 
 import math
 
-import jax
 import jax.numpy as jnp
+import numpy as np
 
-from repro.kernels.quantize.kernel import (
-    BLOCK,
-    dequantize,
-    quantize,
-    quantize_plane as _quantize_plane_kernel,
-)
-
-
-def _pad_to_block(x_flat):
-    n = x_flat.shape[0]
-    pad = (-n) % BLOCK
-    if pad:
-        x_flat = jnp.concatenate([x_flat, jnp.zeros((pad,), x_flat.dtype)])
-    return x_flat, n
+from repro.kernels import prng
+from repro.kernels.quantize.kernel import lane_width, quantize_rows, tile_rows
 
 
 def wire_len(n, bits):
     """Exact wire bytes of the quantized stream: one int8 per element
     (b=8) or one nibble-packed uint8 per element pair (b=4)."""
     return n if bits == 8 else -(-n // 2)
+
+
+def _quantize_seeded(s0, s1, xf, *, bits, interpret):
+    """``xf [M, n]`` under per-message seed pairs ``s0``/``s1`` [M] ->
+    ``(q [M, wire_len], scale [M])``."""
+    m, n = xf.shape
+    # per-row inf-norm, floored so an all-zero row stays finite
+    scale = jnp.maximum(
+        jnp.max(jnp.abs(xf), axis=-1), jnp.finfo(jnp.float32).tiny
+    )
+    mult = np.float32(2 ** (bits - 1) - 1) / scale
+    width = lane_width(bits)
+    rows = -(-n // width)
+    tb = tile_rows(rows)
+    rows = -(-rows // tb) * tb
+    xf = jnp.pad(xf, ((0, 0), (0, rows * width - n)))
+    q = quantize_rows(
+        s0, s1, mult, xf.reshape(m, rows, width), bits=bits,
+        interpret=interpret,
+    )
+    return q.reshape(m, -1)[:, : wire_len(n, bits)], scale
+
+
+def plane_ids(ids, lead, fill):
+    """Per-message ids of a ``lead``-shaped message batch, flattened to
+    [M] uint32 (``None`` -> ``fill`` for every message)."""
+    if ids is None:
+        return jnp.full((max(math.prod(lead), 1),), fill, jnp.uint32)
+    return jnp.broadcast_to(ids, lead).reshape(-1).astype(jnp.uint32)
 
 
 def quantize_plane(seed, sids, rids, x, *, bits=8, interpret=None):
@@ -42,30 +58,15 @@ def quantize_plane(seed, sids, rids, x, *, bits=8, interpret=None):
     stream is materialized in HBM.  ``rids=None`` marks one-to-all
     broadcast messages.  Returns ``(q [..., wire_len], scale [...])``.
     """
-    from repro.kernels import prng
-    from repro.kernels.sparse_gather.ops import _plane_ids
-
     lead, n = x.shape[:-1], x.shape[-1]
-    xf = x.reshape(-1, n).astype(jnp.float32)
-    scale = jnp.maximum(
-        jnp.max(jnp.abs(xf), axis=-1), jnp.finfo(jnp.float32).tiny
+    s0, s1 = prng.fold(
+        seed, plane_ids(sids, lead, 0), plane_ids(rids, lead, prng.BROADCAST)
     )
-    n_pad = -(-n // BLOCK) * BLOCK
-    if n_pad != n:
-        xf = jnp.concatenate(
-            [xf, jnp.zeros((xf.shape[0], n_pad - n), xf.dtype)], axis=-1
-        )
-    q = _quantize_plane_kernel(
-        seed,
-        _plane_ids(sids, lead, 0),
-        _plane_ids(rids, lead, prng.BROADCAST),
-        xf,
-        scale,
-        bits=bits,
+    q, scale = _quantize_seeded(
+        s0, s1, x.reshape(-1, n).astype(jnp.float32), bits=bits,
         interpret=interpret,
     )
-    nb = wire_len(n, bits)
-    return q[:, :nb].reshape(lead + (nb,)), scale.reshape(lead)
+    return q.reshape(lead + q.shape[-1:]), scale.reshape(lead)
 
 
 def dequantize_plane(q, scale, *, n, bits=8, out_dtype=jnp.float32):
@@ -84,34 +85,20 @@ def dequantize_plane(q, scale, *, n, bits=8, out_dtype=jnp.float32):
 
 
 def quantize_tensor(key, x, *, bits=8, interpret=None):
-    """Returns payload {"q", "scale"} with kernel-quantized wire data.
-
-    All payload entries are arrays (the payload moves through vmapped
-    compression and the neighbor exchange as a pytree); the original
-    element count is recovered from the target shape on dequantize.
-    ``interpret=None`` auto-selects by backend (compiled on TPU,
-    interpret elsewhere)."""
-    flat = jnp.reshape(x, (-1,)).astype(jnp.float32)
-    scale = jnp.maximum(jnp.max(jnp.abs(flat)), jnp.finfo(jnp.float32).tiny)
-    padded, n = _pad_to_block(flat)
-    rnd = jax.random.bits(key, (padded.shape[0],), jnp.uint32)
-    q = quantize(padded, rnd, scale, bits=bits, interpret=interpret)
-    # exact wire bytes on the payload (the pad tail is derivable, so it
-    # never travels — Payload.wire_bytes stays honest)
-    return {"q": q[: wire_len(n, bits)], "scale": scale}
-
-
-def dequantize_tensor(payload, shape, dtype=jnp.float32, *, bits=8,
-                      interpret=None):
-    n = math.prod(shape)
-    q, _ = _pad_to_block(payload["q"]) if bits == 8 else (payload["q"], n)
-    if bits == 4:  # re-pad the nibble stream to BLOCK/2-aligned bytes
-        pad = (-q.shape[0]) % (BLOCK // 2)
-        if pad:
-            q = jnp.concatenate([q, jnp.full((pad,), 0x88, q.dtype)])
-    n_padded = q.shape[0] * (1 if bits == 8 else 2)
-    x = dequantize(
-        q, payload["scale"], bits=bits, n=n_padded,
-        out_dtype=dtype, interpret=interpret,
+    """One tensor as a one-message plane whose seed pair is the key's
+    own (``prng.key_seed``).  Returns payload {"q", "scale"} with exact
+    wire bytes (the pad tail never travels)."""
+    s0, s1 = prng.key_seed(key)
+    q, scale = _quantize_seeded(
+        s0[None], s1[None], jnp.reshape(x, (1, -1)).astype(jnp.float32),
+        bits=bits, interpret=interpret,
     )
-    return jnp.reshape(x[:n], shape)
+    return {"q": q[0], "scale": scale[0]}
+
+
+def dequantize_tensor(payload, shape, dtype=jnp.float32, *, bits=8):
+    x = dequantize_plane(
+        payload["q"], payload["scale"], n=math.prod(shape), bits=bits,
+        out_dtype=dtype,
+    )
+    return jnp.reshape(x, shape)

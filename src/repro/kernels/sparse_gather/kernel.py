@@ -5,17 +5,19 @@ parameters live on a packed ``[N]`` plane (``core/packing.py``): compress
 is "pick k of N values", decompress is "scatter k values back into an
 N-zeros plane with a gain".  Two index regimes, two kernel families:
 
-* **cyclic block** (RandK ``sampler="block"``): the k indices are one
-  contiguous window ``(off + j) % n`` at a seeded random offset.  On TPU
-  a modular window is two dynamic slices; both kernels below reduce it
-  to ONE ``pl.ds`` load per tile by reading from a doubled buffer
-  (gather) / writing into a doubled output that the wrapper folds with
-  one add (scatter).  Memory-bound single sweeps — exactly what the
-  VMEM pipeline wants.
-* **arbitrary indices** (RandK ``sampler="uniform"``, TopK): per-tile
-  vector gather ``x_ref[idx]`` / one-shot scatter.  Dynamic vector
-  indexing lowers on recent Mosaic; on older TPU toolchains keep these
-  in interpret mode (the ops wrapper auto-selects interpret off-TPU).
+* **affine** (RandK ``sampler="block"``, stride 1, and
+  ``sampler="stride"``): the k indices are ``(off + j * stride) % n``.
+  ``affine_gather``/``affine_scatter`` take each message's
+  ``(off, stride)`` as scalar-prefetch (SMEM) operands and walk the
+  index set in-kernel, so no index array exists in HBM.  A v5e vector
+  unit cannot load from arbitrary VMEM addresses, so each element is
+  one dynamic-row load plus a lane rotate; the message row stays
+  resident in VMEM.
+* **arbitrary indices** (RandK ``sampler="uniform"``, TopK):
+  ``gather``/``scatter`` index per element (``x_ref[idx]``), which the
+  TPU compiler refuses ("Cannot do int indexing on TPU").  They run in
+  interpret mode only; on a TPU those compressors resolve ``impl=auto``
+  to jnp (``core.compression.COMPRESSORS``).
 
 All kernels validate bit-exactly against ``ref.py`` — the index
 derivation stays seed-synchronized with ``core.compression``, so the
@@ -27,11 +29,17 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.quantize.kernel import resolve_interpret
+from repro.kernels import prng, resolve_interpret
 
 BLOCK = 1024  # elements per VMEM tile (multiple of 128 lanes)
+LANES = 128
+TILE_ROWS = 8  # affine_gather output tile: (8, 128) f32
+# VMEM a resident affine-kernel row may take (v5e has 128 MiB)
+VMEM_BUDGET = 96 * 2**20
 
 
 # ---------------------------------------------------------------------------
@@ -97,180 +105,148 @@ def scatter(values, idx, gain, *, n, interpret=None):
 
 
 # ---------------------------------------------------------------------------
-# Cyclic-block gather / scatter (RandK block sampler)
+# Affine-index gather / scatter (RandK block and stride samplers)
 # ---------------------------------------------------------------------------
 
 
-def _cyclic_gather_kernel(off_ref, x2_ref, out_ref):
-    i = pl.program_id(0)
-    out_ref[...] = x2_ref[pl.ds(off_ref[0] + i * BLOCK, BLOCK)]
+def _vmem_limit(n, *row_bytes):
+    """Scoped-VMEM request for double-buffered resident rows; a message
+    too large for VMEM (or for int32 index walks) fails here, before
+    compiling."""
+    assert n <= prng.MAX_N, n
+    need = 2 * sum(row_bytes) + 2**20
+    if need > VMEM_BUDGET:
+        raise ValueError(
+            f"affine RandK kernel needs {need} B of VMEM for one message "
+            f"(budget {VMEM_BUDGET} B); use impl=jnp for this plane width"
+        )
+    return max(need, 16 * 2**20)
 
 
-@functools.partial(jax.jit, static_argnames=("k", "interpret"))
-def cyclic_gather(x2, off, *, k, interpret=None):
-    """out[j] = x2[off + j] for j < k_pad — the modular window
-    ``(off + j) % n`` after the wrapper doubles the buffer.  One dynamic
-    slice per tile.
-    """
-    interpret = resolve_interpret(interpret)
-    (n2,) = x2.shape
-    k_pad = -(-k // BLOCK) * BLOCK
-    off = jnp.reshape(off.astype(jnp.int32), (1,))
-    return pl.pallas_call(
-        _cyclic_gather_kernel,
-        grid=(k_pad // BLOCK,),
-        in_specs=[
-            pl.BlockSpec((1,), lambda i: (0,)),
-            pl.BlockSpec((n2,), lambda i: (0,)),
-        ],
-        out_specs=pl.BlockSpec((BLOCK,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((k_pad,), x2.dtype),
-        interpret=interpret,
-    )(off, x2)[:k]
+def _rotate_to(row, src_lane, dst_lane):
+    """Move lane ``src_lane`` of a (1, 128) row to lane ``dst_lane``."""
+    return pltpu.roll(row, (dst_lane - src_lane) % LANES, 1)
 
 
-# ---------------------------------------------------------------------------
-# Fused RandK plane compress/decompress: in-kernel counter-PRNG indices
-# ---------------------------------------------------------------------------
-#
-# The seeded wire format's whole point is that RandK indices never travel;
-# these kernels complete the picture by never materializing them in HBM
-# either.  Each grid tile derives its own slice of the affine index set
-# (off + j * stride) % n from the counter PRNG (repro.kernels.prng) with
-# the per-message seed folded in-kernel from (round seed, sender,
-# receiver) — sender and receiver run the SAME derivation, so only the
-# round seed needs to be synchronized, exactly as in the jnp path.
+def _step(idx, stride, n):
+    idx = idx + stride
+    return jnp.where(idx >= n, idx - n, idx)
 
 
-def _affine_tile(seed_ref, sid_ref, rid_ref, *, n, tile, strides):
-    """This tile's slice of the seeded affine index set, in-register."""
-    from repro.kernels import prng
+def _affine_gather_kernel(off_ref, stride_ref, x_ref, o_ref, *, n):
+    m, t = pl.program_id(0), pl.program_id(1)
+    off, stride = off_ref[m], stride_ref[m]
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, LANES), 1)
+    j0 = t * (TILE_ROWS * LANES)
+    idx0 = (off + prng.mulmod(j0, stride, n)) % np.int32(n)
 
-    es = prng.fold((seed_ref[0], seed_ref[1]), sid_ref[0], rid_ref[0])
-    off = prng.derive_offset(es, n)
-    # scalar select chain over the static table (a jnp table would be a
-    # captured const array — disallowed in kernels, and pointless HBM)
-    slot = prng.derive_stride_slot(es, len(strides))
-    stride = jnp.int32(strides[0])
-    for t, s in enumerate(strides[1:], start=1):
-        stride = jnp.where(slot == t, jnp.int32(s), stride)
-    i = pl.program_id(1)
-    j = jax.lax.broadcasted_iota(jnp.int32, (1, tile), 1) + i * tile
-    return j, (off + j * stride) % n
+    def row(r, idx):
+        def elem(c, carry):
+            idx, acc = carry
+            src = x_ref[pl.ds(idx // LANES, 1), :]
+            val = _rotate_to(src, idx % LANES, c)
+            return _step(idx, stride, n), jnp.where(lane == c, val, acc)
 
+        idx, acc = jax.lax.fori_loop(
+            0, LANES, elem, (idx, jnp.zeros((1, LANES), o_ref.dtype))
+        )
+        o_ref[pl.ds(r, 1), :] = acc
+        return idx
 
-def _randk_gather_plane_kernel(seed_ref, sid_ref, rid_ref, x_ref, out_ref,
-                               *, n, strides):
-    _, idx = _affine_tile(
-        seed_ref, sid_ref, rid_ref, n=n, tile=BLOCK, strides=strides
-    )
-    out_ref[...] = x_ref[...][0, idx[0]][None, :]
+    jax.lax.fori_loop(0, TILE_ROWS, row, idx0)
 
 
 @functools.partial(
-    jax.jit, static_argnames=("n", "k", "strides", "interpret")
+    jax.jit, static_argnames=("n", "out_rows", "interpret")
 )
-def randk_gather_plane(seed, sids, rids, x, *, n, k, strides,
-                       interpret=None):
-    """Fused RandK compress of a whole message plane: ONE pallas launch.
+def affine_gather(off, stride, x, *, n, out_rows, interpret=None):
+    """``out[m, j] = x[m, (off[m] + j * stride[m]) % n]`` for ``j <
+    out_rows * 128``.
 
-    ``x [M, n_pad]`` holds M messages (the slot-batched ``[A, S, N]``
-    plane flattened to rows, zero-padded to a BLOCK multiple — indices
-    are taken mod the TRUE n, so padding is never sampled); returns
-    ``[M, k_pad]`` with the seeded affine index set of each message
-    gathered out.  ``strides`` is the static coprime table (``(1,)`` for
-    the block sampler); ``k``/``n``/``strides`` are compile-time, the
-    only runtime inputs are the seed pair, the id vectors and the plane.
+    ``x [M, R, 128]`` holds each message (true length ``n``, zero-padded)
+    as rows of 128 lanes; ``off``/``stride`` [M] int32 with ``0 <= off,
+    stride < n``; ``out_rows`` is a multiple of 8.  Indices are reduced
+    mod the TRUE n, so padding is never sampled.  Returns ``[M,
+    out_rows, 128]``.
     """
     interpret = resolve_interpret(interpret)
-    m, n_pad = x.shape
-    assert n <= n_pad, (n, n_pad)
-    k_pad = -(-k // BLOCK) * BLOCK
+    m, rows, _ = x.shape
+    assert out_rows % TILE_ROWS == 0, out_rows
+    itemsize = jnp.dtype(x.dtype).itemsize
     return pl.pallas_call(
-        functools.partial(
-            _randk_gather_plane_kernel, n=n, strides=strides
+        functools.partial(_affine_gather_kernel, n=n),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(m, out_rows // TILE_ROWS),
+            in_specs=[
+                pl.BlockSpec((None, rows, LANES), lambda m_, t, *_: (m_, 0, 0))
+            ],
+            out_specs=pl.BlockSpec(
+                (None, TILE_ROWS, LANES), lambda m_, t, *_: (m_, t, 0)
+            ),
         ),
-        grid=(m, k_pad // BLOCK),
-        in_specs=[
-            pl.BlockSpec((2,), lambda m_, i: (0,)),
-            pl.BlockSpec((1,), lambda m_, i: (m_,)),
-            pl.BlockSpec((1,), lambda m_, i: (m_,)),
-            pl.BlockSpec((1, n_pad), lambda m_, i: (m_, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, BLOCK), lambda m_, i: (m_, i)),
-        out_shape=jax.ShapeDtypeStruct((m, k_pad), x.dtype),
+        out_shape=jax.ShapeDtypeStruct((m, out_rows, LANES), x.dtype),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_vmem_limit(
+                n, rows * LANES * itemsize, TILE_ROWS * LANES * itemsize
+            )
+        ),
         interpret=interpret,
-    )(jnp.stack(seed), sids, rids, x)
+    )(off, stride, x)
 
 
-def _randk_scatter_plane_kernel(seed_ref, sid_ref, rid_ref, v_ref, out_ref,
-                                *, n, n_pad, k, gain, strides):
-    j, idx = _affine_tile(
-        seed_ref, sid_ref, rid_ref, n=n, tile=v_ref.shape[1],
-        strides=strides,
-    )
-    # pad lanes (j >= k) aim past the plane and are dropped
-    idx = jnp.where(j < k, idx, n_pad)
-    vals = (gain * v_ref[...].astype(jnp.float32)).astype(out_ref.dtype)
-    zeros = jnp.zeros((n_pad,), out_ref.dtype)
-    out_ref[...] = zeros.at[idx[0]].set(vals[0], mode="drop")[None, :]
+def _affine_scatter_kernel(off_ref, stride_ref, v_ref, o_ref, *, n, k,
+                           gain):
+    m = pl.program_id(0)
+    stride = stride_ref[m]
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, LANES), 1)
+    o_ref[...] = jnp.zeros(o_ref.shape, o_ref.dtype)
+
+    def elem(j, idx):
+        src = v_ref[pl.ds(j // LANES, 1), :]
+        val = _rotate_to(src, j % LANES, idx % LANES)
+        val = (gain * val.astype(jnp.float32)).astype(o_ref.dtype)
+        dst = pl.ds(idx // LANES, 1)
+        o_ref[dst, :] = jnp.where(lane == idx % LANES, val, o_ref[dst, :])
+        return _step(idx, stride, n)
+
+    jax.lax.fori_loop(0, k, elem, off_ref[m])
 
 
 @functools.partial(
-    jax.jit, static_argnames=("n", "k", "gain", "strides", "interpret")
+    jax.jit, static_argnames=("n", "k", "gain", "out_rows", "interpret")
 )
-def randk_scatter_plane(seed, sids, rids, v, *, n, k, gain, strides,
-                        interpret=None):
-    """Fused RandK decompress: re-derive each message's index set
-    in-kernel and scatter ``gain * v`` into an ``[M, n_pad]`` zero plane
-    (one grid step per message; the wrapper slices off the padding).
-    ``v [M, k_pad]`` may be k-padded — pad lanes are dropped, not
-    scattered.
+def affine_scatter(off, stride, v, *, n, k, gain, out_rows,
+                   interpret=None):
+    """Inverse of ``affine_gather``: ``out[m] = zeros`` with ``out[m,
+    (off[m] + j * stride[m]) % n] = gain * v[m, j]`` for ``j < k``.
+
+    ``v [M, Rk, 128]`` holds the k values per message (pad lanes are
+    never read); returns ``[M, out_rows, 128]``, rows of 128 lanes
+    covering at least n elements (one grid step per message).
     """
     interpret = resolve_interpret(interpret)
-    m, k_pad = v.shape
-    n_pad = -(-n // BLOCK) * BLOCK
+    m, rows, _ = v.shape
+    itemsize = jnp.dtype(v.dtype).itemsize
     return pl.pallas_call(
         functools.partial(
-            _randk_scatter_plane_kernel, n=n, n_pad=n_pad, k=k,
-            gain=float(gain), strides=strides,
+            _affine_scatter_kernel, n=n, k=k, gain=np.float32(gain)
         ),
-        grid=(m, 1),
-        in_specs=[
-            pl.BlockSpec((2,), lambda m_, i: (0,)),
-            pl.BlockSpec((1,), lambda m_, i: (m_,)),
-            pl.BlockSpec((1,), lambda m_, i: (m_,)),
-            pl.BlockSpec((1, k_pad), lambda m_, i: (m_, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, n_pad), lambda m_, i: (m_, 0)),
-        out_shape=jax.ShapeDtypeStruct((m, n_pad), v.dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(m,),
+            in_specs=[
+                pl.BlockSpec((None, rows, LANES), lambda m_, *_: (m_, 0, 0))
+            ],
+            out_specs=pl.BlockSpec(
+                (None, out_rows, LANES), lambda m_, *_: (m_, 0, 0)
+            ),
+        ),
+        out_shape=jax.ShapeDtypeStruct((m, out_rows, LANES), v.dtype),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_vmem_limit(
+                n, rows * LANES * itemsize, out_rows * LANES * itemsize
+            )
+        ),
         interpret=interpret,
-    )(jnp.stack(seed), sids, rids, v)
-
-
-def _cyclic_scatter_kernel(off_ref, vp_ref, out_ref, *, base):
-    i = pl.program_id(0)
-    out_ref[...] = vp_ref[pl.ds(i * BLOCK - off_ref[0] + base, BLOCK)]
-
-
-@functools.partial(jax.jit, static_argnames=("n2p", "interpret"))
-def cyclic_scatter(vp, off, *, n2p, interpret=None):
-    """out2[p] = vp[p - off + n2p] over a doubled output plane of length
-    ``n2p`` (vp is zero-padded so every tile is one in-bounds ``pl.ds``
-    read); the wrapper folds ``out2[:n] + out2[n:2n]`` to undo the
-    doubling.
-    """
-    interpret = resolve_interpret(interpret)
-    assert vp.shape[0] == 2 * n2p, (vp.shape, n2p)
-    off = jnp.reshape(off.astype(jnp.int32), (1,))
-    return pl.pallas_call(
-        functools.partial(_cyclic_scatter_kernel, base=n2p),
-        grid=(n2p // BLOCK,),
-        in_specs=[
-            pl.BlockSpec((1,), lambda i: (0,)),
-            pl.BlockSpec((2 * n2p,), lambda i: (0,)),
-        ],
-        out_specs=pl.BlockSpec((BLOCK,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((n2p,), vp.dtype),
-        interpret=interpret,
-    )(off, vp)
+    )(off, stride, v)

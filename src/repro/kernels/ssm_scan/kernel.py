@@ -21,6 +21,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import resolve_interpret
+
 DEFAULT_CHUNK = 128
 
 
@@ -78,8 +80,10 @@ def _ssd_kernel(x_ref, a_ref, b_ref, c_ref, y_ref, hout_ref, h_ref):
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
-def ssd_scan(x, alog, bmat, cmat, *, chunk=DEFAULT_CHUNK, interpret=True):
-    """Head-major SSD scan.  T % chunk == 0."""
+def ssd_scan(x, alog, bmat, cmat, *, chunk=DEFAULT_CHUNK, interpret=None):
+    """Head-major SSD scan.  T % chunk == 0.  ``interpret=None``:
+    compiled on TPU, interpret mode elsewhere."""
+    interpret = resolve_interpret(interpret)
     b, nh, t, hd = x.shape
     ds = bmat.shape[-1]
     chunk = min(chunk, t)

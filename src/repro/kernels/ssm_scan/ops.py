@@ -12,7 +12,7 @@ import jax.numpy as jnp
 from repro.kernels.ssm_scan.kernel import ssd_scan
 
 
-def ssd_chunked(cfg, x, bmat, cmat, alog, h0=None, interpret=True):
+def ssd_chunked(cfg, x, bmat, cmat, alog, h0=None, interpret=None):
     """Same contract as models.mamba.ssd_chunked (h0 must be None: the
     kernel owns the initial state)."""
     assert h0 is None, "kernel path owns the scan state"

@@ -107,9 +107,7 @@ def dryrun_one(arch_id, shape_name, multi_pod, recipe=None, verbose=True,
     aaxis = agent_axis_for(mesh)
     t0 = time.time()
 
-    # jax >= 0.5.x: set_mesh; 0.4.37 floor: Mesh is itself a context
-    # manager with the same thread-local effect for this use
-    _mesh_ctx = jax.set_mesh(mesh) if hasattr(jax, "set_mesh") else mesh
+    _mesh_ctx = jax.set_mesh(mesh)
     _mesh_ctx.__enter__()
     if shape.kind == "train":
         step_fn, state_ps, init_fn, solver = steps.build_train(
@@ -176,8 +174,6 @@ def dryrun_one(arch_id, shape_name, multi_pod, recipe=None, verbose=True,
     t_compile = time.time() - t0
     mem = compiled.memory_analysis()
     ca = compiled.cost_analysis() or {}
-    if isinstance(ca, (list, tuple)):  # jax 0.4.x: one dict per device
-        ca = ca[0] if ca else {}
     stats = ha.analyze(compiled.as_text())
     terms = ha.roofline_terms(stats)
     mf = model_flops(

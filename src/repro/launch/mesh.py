@@ -7,33 +7,15 @@ there in hierarchical mode (DESIGN.md §3).
 
 Defined as FUNCTIONS so importing this module never touches jax device
 state; the dry-run sets XLA_FLAGS before any jax initialization.
-
-jax-version floor 0.4.37: ``jax.sharding.AxisType`` (and the
-``axis_types=`` kwarg of ``jax.make_mesh``) only exist on newer jax;
-both are optional here — Auto is the default behavior on old versions.
 """
 from __future__ import annotations
 
-import inspect
-
 import jax
-
-try:  # jax >= 0.5.x
-    from jax.sharding import AxisType
-except ImportError:  # 0.4.x: meshes are implicitly Auto
-    AxisType = None
-
-_MAKE_MESH_HAS_AXIS_TYPES = (
-    "axis_types" in inspect.signature(jax.make_mesh).parameters
-)
+from jax.sharding import AxisType
 
 
 def _make_mesh(shape, axes):
-    if AxisType is not None and _MAKE_MESH_HAS_AXIS_TYPES:
-        return jax.make_mesh(
-            shape, axes, axis_types=(AxisType.Auto,) * len(axes)
-        )
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

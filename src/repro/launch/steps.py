@@ -17,6 +17,7 @@ import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from repro.core import vr
@@ -175,8 +176,10 @@ class DivergenceWatchdog:
     beyond ``blowup x`` the best metric seen marks the window poisoned
     and rolls the solver state back to the OLDEST snapshot in the ring
     (the state most distant from the divergence).  Healthy states are
-    snapshotted as device-buffer COPIES, so the ring survives donation
-    of the live state by the jitted chunk runner.
+    snapshotted as host-memory copies: the ring survives donation of
+    the live state by the jitted chunk runner and costs no device
+    memory (at model scale one solver state is several GB, and
+    ``depth`` device copies would not fit beside it).
 
     Rollback does NOT rewind the round counter: the driver keeps
     advancing rounds, so the replayed trajectory diverges from the
@@ -208,7 +211,7 @@ class DivergenceWatchdog:
         m = float(metric)
         if not self._bad(m):
             self._best = min(self._best, m)
-            self._ring.append(jax.tree.map(jnp.array, state))
+            self._ring.append(jax.tree.map(np.array, state))
             self._consecutive = 0
             return state, False
         self.rollbacks += 1
@@ -220,9 +223,9 @@ class DivergenceWatchdog:
             raise RuntimeError(
                 f"divergence watchdog: {self._consecutive} consecutive "
                 f"rollbacks without re-stabilizing (metric={m})")
-        # copy: the caller's jitted chunk donates its input, and the ring
-        # entry must survive for a possible second rollback
-        return jax.tree.map(jnp.array, self._ring[0]), True
+        # fresh device buffers: the caller's jitted chunk donates its
+        # input, and the ring entry must survive a second rollback
+        return jax.tree.map(jnp.asarray, self._ring[0]), True
 
 
 def abstract_train_state(arch_def, cfg, solver):

@@ -31,10 +31,16 @@ checkpoints, watchdog rollbacks) as Chrome-trace JSONL — load it in
 Perfetto or summarize with ``python -m repro.obs.summary out.json``;
 ``--trace-profile DIR`` additionally attaches the jax.profiler device
 trace over the same window.
+
+Fitting one chip: ``--layers N`` and ``--vocab-rows V`` cut depth and
+embedding rows of the published config (widths stay as published); the
+banner prints the cut as ``reduced``.  ``main(argv)`` can be called
+in-process and returns a summary of the run (see ``main``).
 """
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import functools
 import json
 import time
@@ -45,7 +51,7 @@ import numpy as np
 
 from repro.checkpoint import load_checkpoint, save_checkpoint
 from repro.configs import ARCHS
-from repro.core import vr
+from repro.core import compression, vr
 from repro.core.schedule import SCHEDULES, TopologySchedule, build_graph
 from repro.core.solver import (
     SOLVERS,
@@ -55,6 +61,7 @@ from repro.core.solver import (
 )
 from repro.core.topology import TOPOLOGIES
 from repro.data import SyntheticLMDataset
+from repro.launch import compile_cache
 from repro.launch.steps import (
     DivergenceWatchdog,
     TrainRecipe,
@@ -68,6 +75,10 @@ from repro.obs import telemetry, trace
 def build(args):
     arch = ARCHS[args.arch]
     cfg = arch.make_smoke() if args.smoke else arch.make(None)
+    cut = {k: v for k, v in (("n_layers", args.layers),
+                             ("vocab", args.vocab_rows)) if v}
+    if cut:
+        cfg = dataclasses.replace(cfg, **cut)
     if arch.kind == "encdec" or getattr(cfg, "inputs_via_embeds", False):
         raise SystemExit(
             "train.py drives token-LM archs; embed/enc-dec archs are "
@@ -105,14 +116,36 @@ def build(args):
         # every registered solver accepts a faults= param; spec params win
         defaults["faults"] = args.faults
     solver = make_solver(args.solver, graph, ex, est, defaults=defaults)
-    return arch, cfg, solver, loss
+    return arch, cfg, solver, loss, cut
 
 
-def main():
+def _compressor_impl(solver):
+    """Backend the solver's (x-message) compressor resolved to here."""
+    cfg = getattr(solver, "cfg", None)
+    comp = (getattr(cfg, "compressor_x", None)
+            or getattr(solver, "compressor", None)
+            or getattr(cfg, "compressor", None))
+    return None if comp is None else compression.resolved_impl(comp)
+
+
+def main(argv=None):
+    """Run the trainer; ``argv=None`` reads the command line.
+
+    Returns a summary: ``params``, ``reduced`` (the depth/vocab cut),
+    ``compressor_impl``, ``wire_bytes`` (analytic, per agent per round),
+    ``compile_s`` (ahead-of-time compiles of the round chunks),
+    ``pallas_in_round`` (a Pallas kernel in the compiled chunk),
+    ``losses`` (``[round, mean_loss]`` per logged chunk) and
+    ``telemetry`` (the counters with ``--telemetry``, else None).
+    """
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen3-0.6b")
     ap.add_argument("--smoke", action="store_true",
                     help="reduced config (CPU-friendly)")
+    ap.add_argument("--layers", type=int, default=None,
+                    help="cut the config to this many layers")
+    ap.add_argument("--vocab-rows", type=int, default=None,
+                    help="cut the embedding/unembedding to this many rows")
     ap.add_argument("--agents", type=int, default=4)
     ap.add_argument("--solver", default="ltadmm",
                     help=f"solver spec, one of {sorted(SOLVERS)} with "
@@ -173,7 +206,7 @@ def main():
     ap.add_argument("--trace-profile", default=None, metavar="DIR",
                     help="with --trace: also capture a jax.profiler "
                          "device trace into DIR over the run")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
     if args.checkpoint_every and not args.checkpoint:
         ap.error("--checkpoint-every requires --checkpoint PATH")
     if args.trace_profile and not args.trace:
@@ -182,7 +215,8 @@ def main():
     tracer = (trace.Tracer(args.trace, args.trace_profile)
               if args.trace else trace.NULL)
     with tracer.span("build", arch=args.arch, solver=args.solver):
-        arch, cfg, solver, loss = build(args)
+        arch, cfg, solver, loss, cut = build(args)
+    impl = _compressor_impl(solver)
     if args.telemetry:
         solver = telemetry.with_telemetry(solver)
     ds = SyntheticLMDataset(
@@ -192,9 +226,12 @@ def main():
     data = {"tokens": ds.sample(jax.random.key(args.seed))}
 
     params0 = init_params(jax.random.key(args.seed + 1), model_specs(arch, cfg))
-    print(f"# arch={cfg.name} params={param_count(model_specs(arch, cfg)):,} "
+    n_params = param_count(model_specs(arch, cfg))
+    print(f"# arch={cfg.name} params={n_params:,} "
           f"agents={args.agents} solver={args.solver} "
-          f"topology={args.topology_schedule or args.topology}")
+          f"topology={args.topology_schedule or args.topology} "
+          f"compressor_impl={impl}"
+          + (f" reduced={json.dumps(cut)}" if cut else ""))
     # wire accounting: for a time-varying schedule only the links active
     # in a round carry payloads — report the exact round-0 cost alongside
     # the period-mean; static graphs have a single per-round figure.
@@ -253,6 +290,22 @@ def main():
         )
         return state
 
+    compiled = {}
+    summary = {"params": n_params, "reduced": cut, "compressor_impl": impl,
+               "wire_bytes": solver.wire_bytes(params0), "compile_s": 0.0,
+               "pallas_in_round": None, "losses": [], "telemetry": None}
+
+    def chunk_runner(state, first_round, n):
+        """AOT-compile each chunk length once, timing it as set-up."""
+        if n not in compiled:
+            t0 = time.perf_counter()
+            compiled[n] = run_chunk.lower(state, first_round, n).compile()
+            summary["compile_s"] += time.perf_counter() - t0
+            if summary["pallas_in_round"] is None:
+                summary["pallas_in_round"] = (
+                    "tpu_custom_call" in compiled[n].as_text())
+        return compiled[n]
+
     def mean_loss(state):
         x = solver.consensus_params(state)
         pbar = jax.tree.map(lambda t: jnp.mean(t, axis=0), x)
@@ -268,7 +321,8 @@ def main():
             n = min(args.log_every, args.rounds - done)
             with tracer.span("chunk", first_round=done, rounds=n,
                              cold=cold):
-                state = run_chunk(state, jnp.int32(done), n)
+                first = jnp.int32(done)
+                state = chunk_runner(state, first, n)(state, first)
                 if tracer is not trace.NULL:
                     jax.block_until_ready(state)
             cold = False
@@ -287,6 +341,7 @@ def main():
                         "mean_loss": ml, "rollbacks": watchdog.rollbacks,
                     }))
                     continue
+            summary["losses"].append([done - 1, ml])
             print(json.dumps({
                 "round": done - 1,
                 "mean_loss": round(ml, 4),
@@ -305,6 +360,7 @@ def main():
         if args.telemetry:
             tel = {k: np.asarray(v).tolist()
                    for k, v in telemetry.counters(state).items()}
+            summary["telemetry"] = tel
             print(json.dumps({"telemetry": tel}))
         if args.checkpoint:
             x = solver.consensus_params(state)
@@ -317,7 +373,9 @@ def main():
             print(f"# checkpoint written to {args.checkpoint}")
     finally:
         tracer.close()
+    return summary
 
 
 if __name__ == "__main__":
+    compile_cache.enable()
     main()

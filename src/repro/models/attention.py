@@ -110,16 +110,6 @@ def sdpa(q, k, v, mask, use_flash: bool = False):
     return out.reshape(b, t, h, dh)
 
 
-def _pvary(x, axes):
-    fn = getattr(jax.lax, "pvary", None) or getattr(jax.lax, "pcast", None)
-    if fn is None:
-        return x
-    try:
-        return fn(x, tuple(axes))
-    except TypeError:
-        return fn(x, tuple(axes), to="varying")
-
-
 def sdpa_blockwise(q, k, v, *, causal=True, window=None,
                    q_block=512, kv_block=1024, q_offset=0, vary_axes=()):
     """Flash-structured attention at the XLA level: online softmax over KV
@@ -161,7 +151,8 @@ def sdpa_blockwise(q, k, v, *, causal=True, window=None,
         m0 = jnp.full((b, q_block, kh, g), -jnp.inf, jnp.float32)
         l0 = jnp.zeros((b, q_block, kh, g), jnp.float32)
         if vary_axes:  # under shard_map: carries vary with the manual axis
-            acc0, m0, l0 = (_pvary(t_, vary_axes) for t_ in (acc0, m0, l0))
+            acc0, m0, l0 = (jax.lax.pcast(t_, tuple(vary_axes), to="varying")
+                            for t_ in (acc0, m0, l0))
         qpos = q_offset + iq * q_block + jnp.arange(q_block)
 
         def kv_step(carry, ik, valid):

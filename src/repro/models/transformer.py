@@ -295,9 +295,21 @@ def _unit_forward(cfg: ModelConfig, unit_params, shared_params, x, positions):
     return x, aux
 
 
+def compute_params(params, cfg: ModelConfig):
+    """Floating-point params cast to the compute dtype ``cfg.dtype``: the
+    trainer keeps f32 master weights (gradients flow back through the
+    cast in f32) while the layers run in ``cfg.dtype``."""
+    return jax.tree.map(
+        lambda p: p.astype(cfg.dtype)
+        if jnp.issubdtype(p.dtype, jnp.floating) else p,
+        params,
+    )
+
+
 def forward(params, cfg: ModelConfig, tokens=None, embeds=None,
             positions=None, return_hidden=False):
     """Train / prefill forward.  Returns (logits | hidden, aux_loss)."""
+    params = compute_params(params, cfg)
     if embeds is None:
         x = embed(params["embed"], tokens).astype(cfg.dtype)
     else:
@@ -350,6 +362,7 @@ def forward(params, cfg: ModelConfig, tokens=None, embeds=None,
 
 def loss_fn(params, cfg: ModelConfig, batch):
     """batch: {"tokens": [B,T]} or {"embeds": [B,T,d], "labels": [B,T]}."""
+    params = compute_params(params, cfg)
     if cfg.xent_chunks and cfg.tie_embeddings:
         if "embeds" in batch:
             x, aux = forward(params, cfg, embeds=batch["embeds"],

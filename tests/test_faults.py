@@ -326,3 +326,17 @@ def test_watchdog_snapshots_survive_donation():
     assert rb
     np.testing.assert_array_equal(np.asarray(out["x"]),
                                   np.arange(4.0))
+
+
+def test_watchdog_snapshots_live_on_host():
+    """The ring holds host copies, so a model-scale state does not cost
+    ``depth`` extra copies of device memory; rollback hands back device
+    arrays of the same dtypes."""
+    wd = DivergenceWatchdog(depth=2, blowup=10.0)
+    state = {"x": jnp.arange(4.0), "k": jnp.arange(3, dtype=jnp.uint32)}
+    wd.observe(state, 1.0)
+    assert all(isinstance(t, np.ndarray) for t in jax.tree.leaves(wd._ring[0]))
+    out, rb = wd.observe(state, float("nan"))
+    assert rb and all(isinstance(t, jax.Array) for t in jax.tree.leaves(out))
+    assert out["k"].dtype == jnp.uint32
+    np.testing.assert_array_equal(np.asarray(out["x"]), np.arange(4.0))

@@ -7,9 +7,10 @@ import pytest
 
 from repro.kernels.flash_attention import ops as flash_ops
 from repro.kernels.flash_attention import ref as flash_ref
+from repro.kernels import prng
 from repro.kernels.quantize import ops as q_ops
 from repro.kernels.quantize import ref as q_ref
-from repro.kernels.quantize.kernel import BLOCK, resolve_interpret
+from repro.kernels import resolve_interpret
 from repro.kernels.sparse_gather import ops as sg_ops
 from repro.kernels.sparse_gather import ref as sg_ref
 from repro.kernels.ssm_scan.kernel import ssd_scan
@@ -33,17 +34,19 @@ def test_quantize_kernel_matches_ref(bits, shape, dtype):
         jax.random.fold_in(KEY, bits * 1000 + sum(shape)), shape
     ).astype(dtype)
     payload = q_ops.quantize_tensor(KEY, x, bits=bits)
-    flat = jnp.reshape(x, (-1,)).astype(jnp.float32)
-    pad = (-flat.shape[0]) % BLOCK
-    padded = jnp.concatenate([flat, jnp.zeros((pad,))]) if pad else flat
-    rnd = jax.random.bits(KEY, (padded.shape[0],), jnp.uint32)
-    scale = jnp.maximum(jnp.max(jnp.abs(flat)), jnp.finfo(jnp.float32).tiny)
-    expected = q_ref.quantize_ref(padded, rnd, scale, bits=bits)
+    flat = jnp.reshape(x, (1, -1)).astype(jnp.float32)
+    s0, s1 = prng.key_seed(KEY)
+    expected, scale = q_ref.quantize_seeded_ref(
+        s0[None], s1[None], flat, bits=bits
+    )
     # payload carries exact wire bytes — the pad tail never travels
-    assert (payload["q"] == expected[: q_ops.wire_len(flat.shape[0], bits)]).all()
+    assert payload["q"].shape == (q_ops.wire_len(flat.shape[1], bits),)
+    np.testing.assert_array_equal(np.asarray(payload["q"]),
+                                  np.asarray(expected[0]))
+    assert float(payload["scale"]) == float(scale[0])
     rec = q_ops.dequantize_tensor(payload, shape, bits=bits)
     # quantization error bound: one level
-    bound = float(scale) / (2 ** (bits - 1) - 1) + 1e-2
+    bound = float(scale[0]) / (2 ** (bits - 1) - 1) + 1e-2
     assert float(jnp.max(jnp.abs(rec - x.astype(jnp.float32)))) <= bound
 
 

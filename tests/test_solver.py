@@ -188,11 +188,13 @@ PARITY_SPECS = {
 
 @pytest.mark.parametrize("name", sorted(PARITY_SPECS))
 def test_golden_parity_with_pre_refactor_trajectories(name):
-    """Each method under the unified API reproduces the gradient-norm
-    trajectory captured from the pre-refactor ad-hoc implementations
-    (tests/golden_trajectories.json; log-space tolerance absorbs
-    cross-jax-version float jitter — bitwise equal on the capture
-    machine)."""
+    """Each method under the unified API reproduces its pinned
+    gradient-norm trajectory (tests/golden_trajectories.json, first
+    captured from the pre-refactor ad-hoc implementations).  The fixture
+    holds the jax 0.9.0 ``jax.random`` stream (threefry partitionable,
+    the default since jax 0.5), which draws the minibatches and the
+    stochastic rounding; log-space tolerance absorbs float jitter across
+    machines."""
     spec = PARITY_SPECS[name]
     s = solver.make_solver(spec, TOPO, EX, _est_for(spec))
     st = s.init(jnp.zeros((PROB.n_agents, PROB.n)))
