@@ -495,32 +495,36 @@ def _step_packed(
     bz = jax.random.fold_in(round_key, 13)
 
     # ---- 1. local training ------------------------------------------------
-    x_new = local_phase(cfg, topo, vr_est, state.x, state.z, data, round_key)
+    with jax.named_scope("ltadmm.local"):
+        x_new = local_phase(cfg, topo, vr_est, state.x, state.z, data,
+                            round_key)
 
-    # ---- 2-4. sender-side error feedback for x ----------------------------
-    u_new = (
-        state.x_hat
-        if cfg.lean
-        else tree_lerp(state.u, state.x_hat, cfg.eta)
-    )
+    with jax.named_scope("ltadmm.compress"):
+        # ---- 2-4. sender-side error feedback for x ------------------------
+        u_new = (
+            state.x_hat
+            if cfg.lean
+            else tree_lerp(state.u, state.x_hat, cfg.eta)
+        )
 
-    # x is broadcast to every neighbor: one payload per SENDER
-    m_x, dx = compression.plane_compress(
-        cx, lambda aid: _key_x(round_key, aid), bx,
-        agent_ids, None, x_new - u_new, like, exchange=exchange,
-    )
-    x_hat_new = u_new + dx
+        # x is broadcast to every neighbor: one payload per SENDER
+        m_x, dx = compression.plane_compress(
+            cx, lambda aid: _key_x(round_key, aid), bx,
+            agent_ids, None, x_new - u_new, like, exchange=exchange,
+        )
+        x_hat_new = u_new + dx
 
-    # ---- 5-6. sender-side error feedback for z (all slots at once) --------
-    m_z, rec_z = compression.plane_compress(
-        cz, lambda aid, nid: _key_z(round_key, aid, nid), bz,
-        aid2, nbr, state.z - state.s, like, exchange=exchange,
-    )
-    z_hat_own = _masked(state.s + rec_z, mask3)
+        # ---- 5-6. sender-side error feedback for z (all slots at once) ----
+        m_z, rec_z = compression.plane_compress(
+            cz, lambda aid, nid: _key_z(round_key, aid, nid), bz,
+            aid2, nbr, state.z - state.s, like, exchange=exchange,
+        )
+        z_hat_own = _masked(state.s + rec_z, mask3)
 
     # ---- the only cross-agent communication -------------------------------
-    recv_x = exchange.gather_batched(m_x)  # payload leaves [A, S, ...]
-    recv_z = exchange.exchange_batched(m_z)
+    with jax.named_scope("ltadmm.exchange"):
+        recv_x = exchange.gather_batched(m_x)  # payload leaves [A, S, ...]
+        recv_z = exchange.exchange_batched(m_z)
 
     if telemetry.active():
         # one x-message to every neighbor + one z-message per edge;
@@ -532,34 +536,35 @@ def _step_packed(
                    + telemetry.payload_nbytes(m_z, nd=2))
         _emit_round_telemetry(cfg, vr_est, data, deg, per_msg, None, A=A)
 
-    # ---- 7. receiver-side mirrors -----------------------------------------
-    u_nbr_new = (
-        state.x_hat_nbr
-        if cfg.lean
-        else tree_lerp(state.u_nbr, state.x_hat_nbr, cfg.eta)
-    )
+    with jax.named_scope("ltadmm.update"):
+        # ---- 7. receiver-side mirrors -------------------------------------
+        u_nbr_new = (
+            state.x_hat_nbr
+            if cfg.lean
+            else tree_lerp(state.u_nbr, state.x_hat_nbr, cfg.eta)
+        )
 
-    x_hat_nbr_new = u_nbr_new + compression.plane_decompress(
-        cx, lambda sid: _key_x(round_key, sid), bx,
-        nbr, None, recv_x, like, nd=2, exchange=exchange,
-    )
+        x_hat_nbr_new = u_nbr_new + compression.plane_decompress(
+            cx, lambda sid: _key_x(round_key, sid), bx,
+            nbr, None, recv_x, like, nd=2, exchange=exchange,
+        )
 
-    z_hat_nbr = _masked(
-        state.s_tilde + compression.plane_decompress(
-            cz, lambda sid, rid: _key_z(round_key, sid, rid), bz,
-            nbr, aid2, recv_z, like, nd=2, exchange=exchange,
-        ),
-        mask3,
-    )
+        z_hat_nbr = _masked(
+            state.s_tilde + compression.plane_decompress(
+                cz, lambda sid, rid: _key_z(round_key, sid, rid), bz,
+                nbr, aid2, recv_z, like, nd=2, exchange=exchange,
+            ),
+            mask3,
+        )
 
-    # ---- 8. z update, eq. (4) — one fused [A, S, N] expression ------------
-    rrho = cfg.r * cfg.rho
-    z_new = _masked(
-        0.5 * (z_hat_own - z_hat_nbr)
-        + rrho * x_new[:, None]
-        - rrho * (x_hat_new[:, None] - x_hat_nbr_new),
-        mask3,
-    )
+        # ---- 8. z update, eq. (4) — one fused [A, S, N] expression --------
+        rrho = cfg.r * cfg.rho
+        z_new = _masked(
+            0.5 * (z_hat_own - z_hat_nbr)
+            + rrho * x_new[:, None]
+            - rrho * (x_hat_new[:, None] - x_hat_nbr_new),
+            mask3,
+        )
 
     return LTADMMState(
         x=x_new,
@@ -853,64 +858,72 @@ def _step_schedule_packed(
     # Inactive nodes freeze their x / skip local training (see
     # _step_schedule_tree); their edges are off, so all edge state holds
     # through the act-gated selects below.
-    x_new = local_phase(cfg, topo, vr_est, state.x, state.z, data, round_key)
-    x_new = _select_agents(node_k, x_new, state.x)
+    with jax.named_scope("ltadmm.local"):
+        x_new = local_phase(cfg, topo, vr_est, state.x, state.z, data,
+                            round_key)
+        x_new = _select_agents(node_k, x_new, state.x)
 
-    # ---- 2-4. per-edge sender-side error feedback for x -------------------
-    xh = state.x_hat_edge  # [A, S, N]
-    u_adv = xh if cfg.lean else tree_lerp(state.u_edge, xh, cfg.eta)
+    with jax.named_scope("ltadmm.compress"):
+        # ---- 2-4. per-edge sender-side error feedback for x ---------------
+        xh = state.x_hat_edge  # [A, S, N]
+        u_adv = xh if cfg.lean else tree_lerp(state.u_edge, xh, cfg.eta)
 
-    m_x, rec_x = compression.plane_compress(
-        cx, lambda aid, nid: _key_xe(round_key, aid, nid), bxe,
-        aid2, nbr, x_new[:, None] - u_adv, like,
-        exchange=exchange,
-    )
+        m_x, rec_x = compression.plane_compress(
+            cx, lambda aid, nid: _key_xe(round_key, aid, nid), bxe,
+            aid2, nbr, x_new[:, None] - u_adv, like,
+            exchange=exchange,
+        )
 
-    # ---- 5-6. sender-side error feedback for z (gated below) --------------
-    m_z, rec_z = compression.plane_compress(
-        cz, lambda aid, nid: _key_z(round_key, aid, nid), bz,
-        aid2, nbr, state.z - state.s, like, exchange=exchange,
-    )
-    z_hat_own = state.s + rec_z
+        # ---- 5-6. sender-side error feedback for z (gated below) ----------
+        m_z, rec_z = compression.plane_compress(
+            cz, lambda aid, nid: _key_z(round_key, aid, nid), bz,
+            aid2, nbr, state.z - state.s, like, exchange=exchange,
+        )
+        z_hat_own = state.s + rec_z
 
     # ---- the only cross-agent communication (all slots, every round) ------
     tx_x, tx_z = m_x, m_z  # what actually hits the wire (sealed if faulted)
     fault_counters = None
-    if fp is None:
-        recv_x = exchange.exchange_batched(m_x)
-        recv_z = exchange.exchange_batched(m_z)
-    else:
-        # seal -> fault-armed exchange -> verify: a failed checksum or
-        # stale/poisoned round tag marks the slot not-ok; both payloads
-        # of a round share the link, so one ok mask covers x and z
-        armed = dataclasses.replace(exchange, faults=fp)
-        tx_x = compression.seal_plane(m_x, state.k, nd=2)
-        tx_z = compression.seal_plane(m_z, state.k, nd=2)
-        recv_x, ok_x, crc_x, tag_x = compression.verify_plane_kinds(
-            armed.exchange_batched(tx_x, round_index=state.k), state.k)
-        recv_z, ok_z, crc_z, tag_z = compression.verify_plane_kinds(
-            armed.exchange_batched(tx_z, round_index=state.k), state.k)
-        ok = ok_x & ok_z & alive[:, None]
-        # NAK symmetrization over the (assumed reliable) control plane:
-        # an edge advances only when BOTH endpoints received cleanly,
-        # else duals + EF mirrors hold on both sides in lockstep
-        edge_ok = ok & exchange.exchange_batched(ok)
-        act = act & edge_ok[:, :, None]
-        if telemetry.active():
-            # receiver-side detection verdicts, counted per message on
-            # schedule-active slots (dark union slots carry placeholders)
-            sched_act = sched.round_mask(state.k)
+    with jax.named_scope("ltadmm.exchange"):
+        if fp is None:
+            recv_x = exchange.exchange_batched(m_x)
+            recv_z = exchange.exchange_batched(m_z)
+        else:
+            # seal -> fault-armed exchange -> verify: a failed checksum or
+            # stale/poisoned round tag marks the slot not-ok; both payloads
+            # of a round share the link, so one ok mask covers x and z
+            armed = dataclasses.replace(exchange, faults=fp)
+            tx_x = compression.seal_plane(m_x, state.k, nd=2)
+            tx_z = compression.seal_plane(m_z, state.k, nd=2)
+            recv_x, ok_x, crc_x, tag_x = compression.verify_plane_kinds(
+                armed.exchange_batched(tx_x, round_index=state.k), state.k)
+            recv_z, ok_z, crc_z, tag_z = compression.verify_plane_kinds(
+                armed.exchange_batched(tx_z, round_index=state.k), state.k)
+            ok = ok_x & ok_z & alive[:, None]
+            # NAK symmetrization over the (assumed reliable) control
+            # plane: an edge advances only when BOTH endpoints received
+            # cleanly, else duals + EF mirrors hold on both sides in
+            # lockstep
+            edge_ok = ok & exchange.exchange_batched(ok)
+            act = act & edge_ok[:, :, None]
+            if telemetry.active():
+                # receiver-side detection verdicts, counted per message
+                # on schedule-active slots (dark union slots carry
+                # placeholders)
+                sched_act = sched.round_mask(state.k)
 
-            def _per_agent(mask):
-                return jnp.sum(sched_act & mask, axis=1, dtype=jnp.uint32)
+                def _per_agent(mask):
+                    return jnp.sum(sched_act & mask, axis=1,
+                                   dtype=jnp.uint32)
 
-            fault_counters = {
-                "rx_crc_rejects": _per_agent(~crc_x) + _per_agent(~crc_z),
-                "rx_tag_rejects": (_per_agent(crc_x & ~tag_x)
-                                   + _per_agent(crc_z & ~tag_z)),
-                "rx_dropped": _per_agent(~ok_x) + _per_agent(~ok_z),
-                "naks": _per_agent(ok & ~edge_ok),
-            }
+                fault_counters = {
+                    "rx_crc_rejects": (_per_agent(~crc_x)
+                                       + _per_agent(~crc_z)),
+                    "rx_tag_rejects": (_per_agent(crc_x & ~tag_x)
+                                       + _per_agent(crc_z & ~tag_z)),
+                    "rx_dropped": _per_agent(~ok_x) + _per_agent(~ok_z),
+                    "naks": _per_agent(ok & ~edge_ok),
+                }
     if telemetry.active():
         # transmission is charged on the SCHEDULE's active edges (a
         # dropped message was still sent); faults only add rx counters
@@ -920,43 +933,48 @@ def _step_schedule_packed(
                    + telemetry.payload_nbytes(tx_z, nd=2))
         _emit_round_telemetry(cfg, vr_est, data, deg, per_msg, node_k, A=A,
                               fault_counters=fault_counters)
-    x_hat_edge_new = jnp.where(act, u_adv + rec_x, xh)
-    u_edge_new = (
-        None if cfg.lean else jnp.where(act, u_adv, state.u_edge)
-    )
+    with jax.named_scope("ltadmm.compress"):
+        x_hat_edge_new = jnp.where(act, u_adv + rec_x, xh)
+        u_edge_new = (
+            None if cfg.lean else jnp.where(act, u_adv, state.u_edge)
+        )
 
-    # ---- 7. receiver-side mirrors, gated by the same mask -----------------
-    xhn = state.x_hat_nbr
-    un_adv = xhn if cfg.lean else tree_lerp(state.u_nbr, xhn, cfg.eta)
+    with jax.named_scope("ltadmm.update"):
+        # ---- 7. receiver-side mirrors, gated by the same mask -------------
+        xhn = state.x_hat_nbr
+        un_adv = xhn if cfg.lean else tree_lerp(state.u_nbr, xhn, cfg.eta)
 
-    xhn_adv = un_adv + compression.plane_decompress(
-        cx, lambda sid, rid: _key_xe(round_key, sid, rid), bxe,
-        nbr, aid2, recv_x, like, nd=2, exchange=exchange,
-    )
-    x_hat_nbr_new = jnp.where(act, xhn_adv, xhn)
-    u_nbr_new = (
-        None if cfg.lean else jnp.where(act, un_adv, state.u_nbr)
-    )
+        xhn_adv = un_adv + compression.plane_decompress(
+            cx, lambda sid, rid: _key_xe(round_key, sid, rid), bxe,
+            nbr, aid2, recv_x, like, nd=2, exchange=exchange,
+        )
+        x_hat_nbr_new = jnp.where(act, xhn_adv, xhn)
+        u_nbr_new = (
+            None if cfg.lean else jnp.where(act, un_adv, state.u_nbr)
+        )
 
-    z_hat_nbr = state.s_tilde + compression.plane_decompress(
-        cz, lambda sid, rid: _key_z(round_key, sid, rid), bz,
-        nbr, aid2, recv_z, like, nd=2, exchange=exchange,
-    )
+        z_hat_nbr = state.s_tilde + compression.plane_decompress(
+            cz, lambda sid, rid: _key_z(round_key, sid, rid), bz,
+            nbr, aid2, recv_z, like, nd=2, exchange=exchange,
+        )
 
-    # ---- 8. z / s / s̃ updates on active edges only (held elsewhere) ------
-    rrho = cfg.r * cfg.rho
-    z_eq4 = (
-        0.5 * (z_hat_own - z_hat_nbr)
-        + rrho * x_new[:, None]
-        - rrho * (x_hat_edge_new - x_hat_nbr_new)
-    )
+        # ---- 8. z / s / s̃ updates on active edges only (held elsewhere) --
+        rrho = cfg.r * cfg.rho
+        z_eq4 = (
+            0.5 * (z_hat_own - z_hat_nbr)
+            + rrho * x_new[:, None]
+            - rrho * (x_hat_edge_new - x_hat_nbr_new)
+        )
+        z_new = jnp.where(act, z_eq4, state.z)
+        s_new = jnp.where(act, z_hat_own, state.s)
+        s_tilde_new = jnp.where(act, z_hat_nbr, state.s_tilde)
     return LTADMMScheduleState(
         x=x_new,
         x_hat_edge=x_hat_edge_new,
         u_edge=u_edge_new,
-        z=jnp.where(act, z_eq4, state.z),
-        s=jnp.where(act, z_hat_own, state.s),
-        s_tilde=jnp.where(act, z_hat_nbr, state.s_tilde),
+        z=z_new,
+        s=s_new,
+        s_tilde=s_tilde_new,
         x_hat_nbr=x_hat_nbr_new,
         u_nbr=u_nbr_new,
         k=state.k + 1,

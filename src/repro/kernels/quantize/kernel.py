@@ -115,4 +115,5 @@ def quantize_rows(s0, s1, mult, x, *, bits=8, interpret=None):
             (m, rows, LANES), jnp.int8 if bits == 8 else jnp.uint8
         ),
         interpret=interpret,
+        name="quantize_rows",
     )(s0, s1, mult, x)

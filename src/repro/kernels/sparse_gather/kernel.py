@@ -71,6 +71,7 @@ def gather(x_pad, idx_pad, *, interpret=None):
         out_specs=pl.BlockSpec((BLOCK,), lambda i: (i,)),
         out_shape=jax.ShapeDtypeStruct((k,), x_pad.dtype),
         interpret=interpret,
+        name="sparse_gather",
     )(idx_pad, x_pad)
 
 
@@ -101,6 +102,7 @@ def scatter(values, idx, gain, *, n, interpret=None):
         out_specs=pl.BlockSpec((n,), lambda i: (0,)),
         out_shape=jax.ShapeDtypeStruct((n,), values.dtype),
         interpret=interpret,
+        name="sparse_scatter",
     )(idx, values, gain)
 
 
@@ -192,6 +194,7 @@ def affine_gather(off, stride, x, *, n, out_rows, interpret=None):
             )
         ),
         interpret=interpret,
+        name="affine_gather",
     )(off, stride, x)
 
 
@@ -249,4 +252,5 @@ def affine_scatter(off, stride, v, *, n, k, gain, out_rows,
             )
         ),
         interpret=interpret,
+        name="affine_scatter",
     )(off, stride, v)

@@ -17,6 +17,10 @@ per-device HLO with loop trip-count multipliers:
 
 All shapes in compiled.as_text() are per-device (post-partitioning), so the
 terms are per-chip — exactly what the roofline formula needs.
+
+``named_scopes`` maps each top-level instruction of a compiled module to
+the ``jax.named_scope`` its ``op_name`` metadata carries (the LT-ADMM
+round's phases, ``launch/train.py``'s ``round_scopes``).
 """
 from __future__ import annotations
 
@@ -74,29 +78,101 @@ class Computation:
     symbols: dict  # op name -> result type string
 
 
-def parse_computations(hlo_text: str) -> dict:
-    comps = {}
-    current = None
+_HEADER_RE = re.compile(r"^(?:ENTRY\s+)?%?([\w.\-]+)\s*\(.*\)\s*->.*\{")
+
+
+def _computation_lines(hlo_text: str):
+    """-> ({computation name: its instruction lines}, ENTRY's name)."""
+    comps, entry, current = {}, None, None
     for line in hlo_text.splitlines():
         stripped = line.strip()
-        header = re.match(r"^(?:ENTRY\s+)?%?([\w.\-]+)\s*\(.*\)\s*->.*\{", stripped)
+        header = _HEADER_RE.match(stripped)
         if header and not line.startswith(" " * 2):
-            current = Computation(header.group(1), [], {})
-            comps[current.name] = current
-            if stripped.startswith("ENTRY") or line.startswith("ENTRY"):
-                comps["__entry__"] = current
+            current = comps[header.group(1)] = []
+            if stripped.startswith("ENTRY"):
+                entry = header.group(1)
             continue
         if current is None:
             continue
         if stripped == "}":
             current = None
             continue
-        m = _OP_RE.match(line)
-        if m:
-            name, rtype, kind = m.groups()
-            current.ops.append(Op(name, kind, rtype, stripped))
-            current.symbols[name] = rtype
+        current.append(line)
+    return comps, entry
+
+
+def parse_computations(hlo_text: str) -> dict:
+    lines, entry = _computation_lines(hlo_text)
+    comps = {}
+    for cname, body in lines.items():
+        comps[cname] = current = Computation(cname, [], {})
+        for line in body:
+            m = _OP_RE.match(line)
+            if m:
+                name, rtype, kind = m.groups()
+                current.ops.append(Op(name, kind, rtype, line.strip()))
+                current.symbols[name] = rtype
+    if entry is not None:
+        comps["__entry__"] = comps[entry]
     return comps
+
+
+_NAME_RE = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s")
+_OP_NAME_RE = re.compile(r'op_name="([^"]*)"')
+# kinds whose lines name called computations: a fusion's computation is
+# fused into it, the others' run as top-level code
+_CONTROL_RE = re.compile(r"\s(fusion|while|conditional|call)\(")
+_SUBCOMP_RE = re.compile(
+    r"\b(?:body|condition|to_apply|true_computation|false_computation)"
+    r"=%?([\w.\-]+)")
+_BRANCHES_RE = re.compile(r"branch_computations=\{([^}]*)\}")
+_FUSED_RE = re.compile(r"\bcalls=%?([\w.\-]+)")
+
+
+def named_scopes(hlo_text: str, prefix: str) -> dict:
+    """Instruction name -> its ``jax.named_scope`` that starts with
+    ``prefix`` (the first such component of its ``op_name``), for every
+    top-level instruction of a compiled module: those of ENTRY and of
+    the computations it runs through while, conditional and call — the
+    ops a device trace shows.  A fusion whose own line carries no
+    metadata takes the scope of its fused computation's root.
+    Instructions under no such scope are left out."""
+    comps, entry = _computation_lines(hlo_text)
+    scope_re = re.compile(r"(?<![\w.])" + re.escape(prefix) + r"\w+")
+
+    def scope(line):
+        m = _OP_NAME_RE.search(line)
+        s = scope_re.search(m.group(1)) if m else None
+        return s.group(0) if s else None
+
+    def root(cname):
+        return next((ln for ln in comps.get(cname, ())
+                     if ln.lstrip().startswith("ROOT")), "")
+
+    out, todo, seen = {}, [entry], set()
+    while todo:
+        cname = todo.pop()
+        if cname in seen or cname not in comps:
+            continue
+        seen.add(cname)
+        for line in comps[cname]:
+            name = _NAME_RE.match(line)
+            if not name:
+                continue
+            kind = _CONTROL_RE.search(line)
+            kind = kind.group(1) if kind else None
+            if kind in ("while", "conditional", "call"):
+                todo += _SUBCOMP_RE.findall(line)
+                for branches in _BRANCHES_RE.findall(line):
+                    todo += [b.strip().lstrip("%")
+                             for b in branches.split(",")]
+            s = scope(line)
+            if kind == "fusion" and "metadata=" not in line:
+                fused = _FUSED_RE.search(line)
+                s = scope(root(fused.group(1))) if fused else None
+            if s:
+                out[name.group(1)] = s
+    return out
 
 
 def _multipliers(comps: dict) -> dict:

@@ -25,12 +25,16 @@ Observability: ``--telemetry`` wraps the solver in the in-trace counter
 plane (``repro.obs.telemetry``) — measured wire bytes, messages,
 fault-plane rejects, participation and gradient evaluations accumulate
 on-device in the scanned state (no host syncs, trajectories unchanged)
-and print as one JSON line at the end.  ``--trace out.json`` writes
-wall-clock spans (build, per-chunk execute with a cold-compile marker,
-checkpoints, watchdog rollbacks) as Chrome-trace JSONL — load it in
-Perfetto or summarize with ``python -m repro.obs.summary out.json``;
-``--trace-profile DIR`` additionally attaches the jax.profiler device
-trace over the same window.
+and print as one JSON line at the end.  The driver loop's spans
+(``train.build``, and per chunk ``train.chunk``, ``train.eval``,
+``train.watchdog``, ``train.log``, ``train.checkpoint``, each with the
+chunk's ``first_round``) are always ``jax.profiler`` annotations, so
+they land in any profiler capture beside the device's ops; the LT-ADMM
+round's ops carry the ``ltadmm.*`` named scopes of its phases.
+``--trace out.json`` also writes the spans as Chrome-trace JSONL —
+load it in Perfetto or summarize with ``python -m repro.obs.summary
+out.json``; ``--trace-profile DIR`` additionally captures the
+jax.profiler device trace over the run.
 
 Fitting one chip: ``--layers N`` and ``--vocab-rows V`` cut depth and
 embedding rows of the published config (widths stay as published); the
@@ -61,7 +65,7 @@ from repro.core.solver import (
 )
 from repro.core.topology import TOPOLOGIES
 from repro.data import SyntheticLMDataset
-from repro.launch import compile_cache
+from repro.launch import compile_cache, hlo_analysis
 from repro.launch.steps import (
     DivergenceWatchdog,
     TrainRecipe,
@@ -135,6 +139,8 @@ def main(argv=None):
     ``compressor_impl``, ``wire_bytes`` (analytic, per agent per round),
     ``compile_s`` (ahead-of-time compiles of the round chunks),
     ``pallas_in_round`` (a Pallas kernel in the compiled chunk),
+    ``round_scopes`` (each top-level instruction of the compiled chunk
+    that runs under an ``ltadmm.*`` named scope -> that scope),
     ``losses`` (``[round, mean_loss]`` per logged chunk) and
     ``telemetry`` (the counters with ``--telemetry``, else None).
     """
@@ -200,9 +206,10 @@ def main(argv=None):
                          "evals) in the solver state; printed as one JSON "
                          "line at the end — trajectories unchanged")
     ap.add_argument("--trace", default=None, metavar="PATH",
-                    help="write wall-clock spans (build, chunks, "
-                         "checkpoints, rollbacks) as Chrome-trace JSONL; "
-                         "summarize with python -m repro.obs.summary PATH")
+                    help="also write the spans (build, chunks, eval, "
+                         "watchdog, log, checkpoints, rollbacks) as "
+                         "Chrome-trace JSONL; summarize with python -m "
+                         "repro.obs.summary PATH")
     ap.add_argument("--trace-profile", default=None, metavar="DIR",
                     help="with --trace: also capture a jax.profiler "
                          "device trace into DIR over the run")
@@ -213,8 +220,8 @@ def main(argv=None):
         ap.error("--trace-profile requires --trace PATH")
 
     tracer = (trace.Tracer(args.trace, args.trace_profile)
-              if args.trace else trace.NULL)
-    with tracer.span("build", arch=args.arch, solver=args.solver):
+              if args.trace else trace.ANNOTATIONS)
+    with tracer.span("train.build", arch=args.arch, solver=args.solver):
         arch, cfg, solver, loss, cut = build(args)
     impl = _compressor_impl(solver)
     if args.telemetry:
@@ -293,7 +300,8 @@ def main(argv=None):
     compiled = {}
     summary = {"params": n_params, "reduced": cut, "compressor_impl": impl,
                "wire_bytes": solver.wire_bytes(params0), "compile_s": 0.0,
-               "pallas_in_round": None, "losses": [], "telemetry": None}
+               "pallas_in_round": None, "round_scopes": None, "losses": [],
+               "telemetry": None}
 
     def chunk_runner(state, first_round, n):
         """AOT-compile each chunk length once, timing it as set-up."""
@@ -302,8 +310,10 @@ def main(argv=None):
             compiled[n] = run_chunk.lower(state, first_round, n).compile()
             summary["compile_s"] += time.perf_counter() - t0
             if summary["pallas_in_round"] is None:
-                summary["pallas_in_round"] = (
-                    "tpu_custom_call" in compiled[n].as_text())
+                text = compiled[n].as_text()
+                summary["pallas_in_round"] = "tpu_custom_call" in text
+                summary["round_scopes"] = hlo_analysis.named_scopes(
+                    text, "ltadmm.")
         return compiled[n]
 
     def mean_loss(state):
@@ -318,18 +328,21 @@ def main(argv=None):
     cold = True
     try:
         while done < args.rounds:
-            n = min(args.log_every, args.rounds - done)
-            with tracer.span("chunk", first_round=done, rounds=n,
+            # spans of one chunk share its first round
+            r0, n = done, min(args.log_every, args.rounds - done)
+            with tracer.span("train.chunk", first_round=r0, rounds=n,
                              cold=cold):
                 first = jnp.int32(done)
                 state = chunk_runner(state, first, n)(state, first)
-                if tracer is not trace.NULL:
+                if isinstance(tracer, trace.Tracer):
                     jax.block_until_ready(state)
             cold = False
             done += n
-            ml = mean_loss(state)
+            with tracer.span("train.eval", first_round=r0):
+                ml = mean_loss(state)
             if watchdog is not None:
-                state, rolled_back = watchdog.observe(state, ml)
+                with tracer.span("train.watchdog", first_round=r0):
+                    state, rolled_back = watchdog.observe(state, ml)
                 if rolled_back:
                     # skip-ahead: restore last-good state but keep
                     # advancing rounds — rewinding would
@@ -342,17 +355,18 @@ def main(argv=None):
                     }))
                     continue
             summary["losses"].append([done - 1, ml])
-            print(json.dumps({
-                "round": done - 1,
-                "mean_loss": round(ml, 4),
-                "consensus_err": float(
-                    consensus_error(solver.consensus_params(state))
-                ),
-                "wall_s": round(time.time() - t_start, 1),
-            }))
+            with tracer.span("train.log", first_round=r0):
+                print(json.dumps({
+                    "round": done - 1,
+                    "mean_loss": round(ml, 4),
+                    "consensus_err": float(
+                        consensus_error(solver.consensus_params(state))
+                    ),
+                    "wall_s": round(time.time() - t_start, 1),
+                }))
             if (args.checkpoint_every and done < args.rounds
                     and done % args.checkpoint_every == 0):
-                with tracer.span("checkpoint", round=done):
+                with tracer.span("train.checkpoint", first_round=r0):
                     save_checkpoint(
                         args.checkpoint + ".state", state, step=done,
                         extra={"arch": args.arch, "smoke": args.smoke,
@@ -365,7 +379,7 @@ def main(argv=None):
         if args.checkpoint:
             x = solver.consensus_params(state)
             pbar = jax.tree.map(lambda t: jnp.mean(t, axis=0), x)
-            with tracer.span("checkpoint", round=args.rounds):
+            with tracer.span("train.checkpoint", round=args.rounds):
                 save_checkpoint(
                     args.checkpoint, pbar, step=args.rounds,
                     extra={"arch": args.arch, "smoke": args.smoke,

@@ -1,11 +1,10 @@
-"""Trace summary CLI: per-phase / per-counter report from Tracer JSONL.
+"""Trace summary CLI: per-phase report from Tracer JSONL.
 
     PYTHONPATH=src python -m repro.obs.summary out.json
 
 Aggregates the Chrome-trace events written by ``obs.trace.Tracer``:
 complete events ("X") are grouped by span name (count, total/mean/max
-wall ms); counter events ("C") report their last sampled values;
-instant events ("i") are listed with their timestamps.
+wall ms); instant events ("i") are listed with their timestamps.
 """
 from __future__ import annotations
 
@@ -17,7 +16,6 @@ from repro.obs.trace import load_events
 
 def summarize(events: list[dict]) -> str:
     spans: dict[str, list[float]] = {}
-    counters: dict[str, dict] = {}
     instants: list[tuple[float, str, dict]] = []
     for ev in events:
         ph = ev.get("ph")
@@ -25,8 +23,6 @@ def summarize(events: list[dict]) -> str:
             spans.setdefault(ev["name"], []).append(
                 float(ev.get("dur", 0.0)) / 1e3
             )
-        elif ph == "C":
-            counters[ev["name"]] = ev.get("args", {})
         elif ph == "i":
             instants.append(
                 (float(ev.get("ts", 0.0)) / 1e3, ev["name"],
@@ -42,14 +38,6 @@ def summarize(events: list[dict]) -> str:
                 f"{name:40s} {len(ds):6d} {sum(ds):11.1f} "
                 f"{sum(ds) / len(ds):10.1f} {max(ds):10.1f}"
             )
-    if counters:
-        out.append("")
-        out.append(f"{'counter':40s} last value")
-        for name in sorted(counters):
-            vals = ", ".join(
-                f"{k}={v}" for k, v in sorted(counters[name].items())
-            )
-            out.append(f"{name:40s} {vals}")
     if instants:
         out.append("")
         out.append(f"{'t ms':>10s}  instant")

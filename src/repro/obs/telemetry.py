@@ -14,7 +14,7 @@ prediction.
 Opt-in is a wrapper, not a flag::
 
     solver = with_telemetry(make_solver(spec, graph, ex, est))
-    state  = solver.init(x0)            # TelemetryState(inner, telemetry)
+    state  = solver.init(x0)            # TelemetryState(inner, telemetry, high)
     state  = solver.step(state, data, key)
     counts = counters(state)            # host numpy dict
 
@@ -41,8 +41,10 @@ Counting conventions (what the parity tests rely on):
   estimator's published recipe (SAGA reset sweeps all ``m``, SVRG
   anchors cost a second batch, ...), charged only to participating
   agents.
-* counters are uint32 and wrap mod 2^32; per-round differences stay
-  exact under wraparound.
+* each counter is carried as two uint32 words, ``telemetry`` (the low
+  word) and ``high`` (the wraps of the low word so far); ``counters``
+  joins them into exact uint64 counts past 2**32.  A round adds less
+  than 2**32 to any counter, so one wrap per round at most.
 """
 from __future__ import annotations
 
@@ -195,7 +197,8 @@ def round_grad_evals(est, m: int, batch_size: int) -> int:
 
 class TelemetryState(NamedTuple):
     inner: Any  # the wrapped solver's state, untouched
-    telemetry: Telemetry
+    telemetry: Telemetry  # low 32 bits of each counter
+    high: Telemetry  # high 32 bits: wraps of the low word so far
 
 
 def _n_agents(tree) -> int:
@@ -208,7 +211,8 @@ class TelemetrySolver:
     pytree alongside the wrapped solver's state.  ``step`` installs the
     trace-time collector, traces the inner step (whose taps add their
     round contributions), and folds the totals into the carried
-    counters — plain uint32 adds in the compiled program, nothing else."""
+    counters — plain uint32 adds and carries in the compiled program,
+    nothing else."""
 
     solver: Any
 
@@ -219,15 +223,21 @@ class TelemetrySolver:
 
     def init(self, x0):
         inner = self.solver.init(x0)
-        return TelemetryState(inner, Telemetry.zeros(_n_agents(x0)))
+        a = _n_agents(x0)
+        return TelemetryState(inner, Telemetry.zeros(a), Telemetry.zeros(a))
 
     def step(self, state, data, key):
         with _collect() as col:
             inner = self.solver.step(state.inner, data, key)
-        tel = state.telemetry
-        upd = {k: getattr(tel, k) + v for k, v in col.items()}
-        upd["rounds"] = tel.rounds + jnp.uint32(1)
-        return TelemetryState(inner, tel._replace(**upd))
+        lo, hi = state.telemetry, state.high
+        inc = dict(col, rounds=jnp.uint32(1))
+        new_lo = {k: getattr(lo, k) + v for k, v in inc.items()}
+        # the low word wrapped iff it came out below where it was
+        wrapped = {k: (new_lo[k] < getattr(lo, k)).astype(jnp.uint32)
+                   for k in inc}
+        new_hi = {k: getattr(hi, k) + w for k, w in wrapped.items()}
+        return TelemetryState(inner, lo._replace(**new_lo),
+                              hi._replace(**new_hi))
 
     def consensus_params(self, state):
         return self.solver.consensus_params(state.inner)
@@ -236,13 +246,13 @@ class TelemetrySolver:
         inner = self.solver.abstract_state(x_sds)
         a = _n_agents(x_sds)
         tel = jax.eval_shape(lambda: Telemetry.zeros(a))
-        return TelemetryState(inner, tel)
+        return TelemetryState(inner, tel, tel)
 
     def state_sharding(self, x_ps, edge_ps, scalar_ps):
         inner = self.solver.state_sharding(x_ps, edge_ps, scalar_ps)
         # counters are tiny; replicate them
         tel = Telemetry(*([scalar_ps] * len(Telemetry._fields)))
-        return TelemetryState(inner, tel)
+        return TelemetryState(inner, tel, tel)
 
 
 def with_telemetry(solver) -> TelemetrySolver:
@@ -252,8 +262,13 @@ def with_telemetry(solver) -> TelemetrySolver:
     return TelemetrySolver(solver)
 
 
-def counters(state) -> dict[str, np.ndarray]:
-    """Host-side numpy view of the cumulative counters (one device->host
-    transfer; call it at sample points, never inside the loop)."""
-    tel = state.telemetry if isinstance(state, TelemetryState) else state
-    return {f: np.asarray(v) for f, v in zip(Telemetry._fields, tel)}
+def counters(state: TelemetryState) -> dict[str, np.ndarray]:
+    """Host-side numpy view of the cumulative counters, exact past
+    2**32 (uint64: the high word shifted over the low one).  One
+    device->host transfer; call it at sample points, never inside the
+    loop."""
+    return {
+        f: (np.asarray(hi, np.uint64) << np.uint64(32))
+        | np.asarray(lo, np.uint64)
+        for f, lo, hi in zip(Telemetry._fields, state.telemetry, state.high)
+    }

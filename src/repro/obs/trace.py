@@ -1,12 +1,19 @@
-"""Wall-clock trace layer: Chrome-trace/Perfetto JSONL spans.
+"""Span layer: named spans on the profiler's clock, and a JSONL writer.
 
-``Tracer`` appends one JSON event per line (after a leading ``[``),
-which is simultaneously a valid unterminated Chrome trace — load it
-directly in ``chrome://tracing`` or Perfetto — and line-parseable by
-``python -m repro.obs.summary out.json``.  Spans cover the phases the
-launch/benchmark stack cares about (compile, warm-up, per-chunk
-execute, checkpoint, watchdog rollback); ``profile_dir`` optionally
-attaches the ``jax.profiler`` device trace over the same window.
+Every span and instant enters a ``jax.profiler.TraceAnnotation`` of the
+same name, its arguments (e.g. ``first_round``) as annotation metadata,
+so the spans land in any ``jax.profiler`` capture on the device trace's
+clock: ``--trace-profile``, or a capture an outside caller (the
+benchmark's traced window) takes around the run.  With no capture
+active a span costs one TraceMe check.
+
+``ANNOTATIONS`` is the default tracer: annotations only, no file.
+``Tracer`` (``--trace PATH``) also appends one JSON event per line
+(after a leading ``[``), which is simultaneously a valid unterminated
+Chrome trace — load it directly in ``chrome://tracing`` or Perfetto —
+and line-parseable by ``python -m repro.obs.summary out.json``;
+``profile_dir`` optionally attaches the ``jax.profiler`` device trace
+over the same run.
 
 Also home of the shared ``timeit`` microbenchmark helper (compile once,
 average ``iters`` timed calls) used by ``benchmarks/common.py`` and
@@ -34,9 +41,31 @@ def timeit(fn, *args, iters=5):
     return (time.perf_counter() - t0) / iters * 1e6  # us
 
 
-class Tracer:
-    """Chrome-trace JSONL writer (one event per line, flushed eagerly so
-    a crashed run still leaves a loadable trace)."""
+class AnnotationTracer:
+    """Spans as ``jax.profiler.TraceAnnotation``s only: writes no file.
+    The default when no ``--trace`` is given, so call sites never
+    branch."""
+
+    @contextlib.contextmanager
+    def span(self, name: str, **args):
+        """Annotation named ``name`` around the with-block."""
+        with jax.profiler.TraceAnnotation(name, **args):
+            yield self
+
+    def instant(self, name: str, **args) -> None:
+        with jax.profiler.TraceAnnotation(name, **args):
+            pass
+
+    def close(self) -> None:
+        pass
+
+
+ANNOTATIONS = AnnotationTracer()
+
+
+class Tracer(AnnotationTracer):
+    """Annotations plus a Chrome-trace JSONL file (one event per line,
+    flushed eagerly so a crashed run still leaves a loadable trace)."""
 
     def __init__(self, path: str, profile_dir: str | None = None):
         self.path = path
@@ -61,10 +90,12 @@ class Tracer:
 
     @contextlib.contextmanager
     def span(self, name: str, **args):
-        """Complete-event ("ph": "X") span around the with-block."""
+        """Annotation plus a complete-event ("ph": "X") line around the
+        with-block."""
         ts = self._now_us()
         try:
-            yield self
+            with super().span(name, **args):
+                yield self
         finally:
             self._event({
                 "name": name, "ph": "X", "pid": 0, "tid": 0,
@@ -74,15 +105,10 @@ class Tracer:
             })
 
     def instant(self, name: str, **args) -> None:
+        super().instant(name, **args)
         self._event({
             "name": name, "ph": "i", "s": "g", "pid": 0, "tid": 0,
             "ts": round(self._now_us(), 1), "args": args,
-        })
-
-    def counter(self, name: str, **values) -> None:
-        self._event({
-            "name": name, "ph": "C", "pid": 0, "tid": 0,
-            "ts": round(self._now_us(), 1), "args": values,
         })
 
     def close(self) -> None:
@@ -97,27 +123,6 @@ class Tracer:
 
     def __exit__(self, *exc):
         self.close()
-
-
-class _NullTracer:
-    """API-compatible no-op — the default when no ``--trace`` is given,
-    so call sites never branch."""
-
-    @contextlib.contextmanager
-    def span(self, name: str, **args):
-        yield self
-
-    def instant(self, name: str, **args) -> None:
-        pass
-
-    def counter(self, name: str, **values) -> None:
-        pass
-
-    def close(self) -> None:
-        pass
-
-
-NULL = _NullTracer()
 
 
 def load_events(path: str) -> list[dict]:

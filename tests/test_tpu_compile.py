@@ -114,12 +114,39 @@ def test_randk_plane_kernels_compile(one_chip, sampler):
     )
 
 
+@pytest.mark.parametrize("name", ["quantize_rows", "affine_gather",
+                                  "affine_scatter"])
+def test_pallas_calls_carry_stable_names(one_chip, name):
+    """The trace reduction finds a kernel by its ``pallas_call`` name,
+    which the lowered program carries as ``kernel_name``."""
+    seed, sids, rids, x = _plane_args(one_chip, N)
+    k = N // 4
+    fn, args = {
+        "quantize_rows": (
+            lambda s, a, b, xx: q_ops.quantize_plane(s, a, b, xx, bits=8),
+            (seed, sids, rids, x)),
+        "affine_gather": (
+            lambda s, a, b, xx: sg_ops.randk_gather_plane(
+                s, a, b, xx, k=k, strides=(1,)),
+            (seed, sids, rids, x)),
+        "affine_scatter": (
+            lambda s, a, b, v: sg_ops.randk_scatter_plane(
+                s, a, b, v, n=N, gain=N / k, strides=(1,)),
+            (seed, sids, rids, _sds(one_chip, (M, k), jnp.float32))),
+    }[name]
+    text = jax.jit(fn).lower(*args).as_text()
+    assert f'kernel_name = "{name}"' in text
+
+
 def test_ltadmm_round_compiles_with_pallas_quantizer(one_chip):
     """One LT-ADMM round of the smoke qwen3 model on the packed plane,
-    qbit through the fused Pallas quantizer."""
+    qbit through the fused Pallas quantizer; every top-level op of its
+    four named phases is found, the quantizer's custom calls in
+    ``ltadmm.compress``."""
     from repro.configs import ARCHS
     from repro.core.schedule import build_graph
     from repro.core.solver import make_solver
+    from repro.launch.hlo_analysis import named_scopes
     from repro.launch.steps import TrainRecipe, build_estimator, model_specs
     from repro.models.common import abstract_params
 
@@ -142,7 +169,13 @@ def test_ltadmm_round_compiles_with_pallas_quantizer(one_chip):
         jax.eval_shape(solver.init, x0),
     )
     data = {"tokens": _sds(one_chip, (agents, 4, 33), jnp.int32)}
-    _compile(
+    compiled = _compile(
         lambda st, d, seed: solver.step(st, d, jax.random.key(seed)),
         state, data, _sds(one_chip, (), jnp.int32),
     )
+    scopes = named_scopes(compiled.as_text(), "ltadmm.")
+    assert set(scopes.values()) == {"ltadmm.local", "ltadmm.compress",
+                                    "ltadmm.exchange", "ltadmm.update"}
+    quantizer = [op for op in scopes if op.startswith("quantize_rows")]
+    assert len(quantizer) == 2  # the x and the z messages
+    assert {scopes[op] for op in quantizer} == {"ltadmm.compress"}
