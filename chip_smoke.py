@@ -12,7 +12,11 @@ One chip:
   (c) ``repro.launch.train.main`` in-process: LT-ADMM on the packed plane
       with the qbit compressor (``impl=auto``), Qwen3-0.6B at its
       published widths with depth and vocabulary rows cut to fit one
-      chip, two agents on a complete graph, measured telemetry on.
+      chip, two agents on a complete graph, measured telemetry on;
+  (d) the divergence watchdog: a prefetched host snapshot outlives the
+      donation of the live state, and a planted NaN rolls back to it bit
+      for bit (run alone: ``python -c "import chip_smoke as c;
+      c.watchdog_phase()"`` with ``src`` on ``PYTHONPATH``).
 
 Four chips (``--chips 4``), and nothing else: four agents on a ring, one
 per chip, through ``launch.steps.build_train`` (one collective-permute
@@ -176,6 +180,53 @@ def train_phase(argv=TRAIN_ARGV):
     print(f"# train: peak HBM bytes {_peak_bytes(jax.devices()[:1])}")
 
 
+def watchdog_phase(n=1 << 22):
+    """The divergence watchdog on the chip: a prefetched snapshot, the
+    live state donated by a jitted step, a planted NaN, then a rollback
+    that must give back the snapshot bit for bit, twice."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from repro.launch.steps import DivergenceWatchdog
+
+    key = jax.random.key(3)
+    state = {"x": jax.random.normal(key, (2, n), jnp.float32),
+             "h": jax.random.normal(key, (2, n // 4), jnp.bfloat16),
+             "k": jnp.arange(n // 8, dtype=jnp.uint32)}
+    # from device copies: reading ``state`` itself would fill its host
+    # cache before the watchdog's transfer does
+    want = jax.tree.map(lambda t: np.array(jnp.copy(t)), state)
+    step = jax.jit(lambda s: jax.tree.map(lambda t: t * 3 + 1, s),
+                   donate_argnums=0)
+    wd = DivergenceWatchdog(depth=2, blowup=10.0)
+    wd.prefetch(state)
+    state, rolled = wd.observe(state, 1.0)
+    check(not rolled, "a healthy point rolled back")
+    snap = wd._ring[0]
+    owned = {k: bool(t.flags.owndata) for k, t in snap.items()}
+    print(f"# watchdog: snapshot leaves own their memory {owned}")
+    check(all(isinstance(t, np.ndarray) for t in snap.values()),
+          "a snapshot leaf is not a host array")
+    for i in range(2):
+        live, state = state, jax.block_until_ready(step(state))
+        check(live["x"].is_deleted(), f"step {i} did not donate the state")
+        state = jax.tree.map(lambda t: t.at[0].set(jnp.nan)
+                             if t.dtype != jnp.uint32 else t, state)
+        wd.prefetch(state)
+        state, rolled = wd.observe(state, float("nan"))
+        check(rolled, f"rollback {i}: the planted NaN did not roll back")
+        bad = {k: int(np.sum(np.asarray(state[k]).view(np.uint8)
+                             != want[k].view(np.uint8)))
+               for k in want}
+        print(f"# watchdog: rollback {i} after donation, bytes that differ "
+              f"from the snapshot {bad}")
+        check(not any(bad.values()), f"rollback {i} differs: {bad}")
+    print(f"# watchdog: counters {wd.counters()}")
+    check(wd.counters()["discarded"] == 2 and wd.counters()["rollbacks"] == 2,
+          f"counters {wd.counters()}")
+
+
 def _mesh_solvers(mesh, recipe, arch, cfg):
     """(ppermute solver, host-simulated solver) on the same graph and the
     same agent-sharded placement."""
@@ -307,6 +358,7 @@ def main(argv=None):
         else:
             parity_phase()
             train_phase()
+            watchdog_phase()
     except PhaseError as e:
         print(f"chip_smoke: FAILED: {e}", file=sys.stderr)
         return 1

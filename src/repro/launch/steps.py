@@ -168,6 +168,22 @@ def build_train(arch_def, cfg, mesh, solver_spec: str,
     return step_fn, state_ps, solver.init, solver
 
 
+def _host_copy(leaf) -> np.ndarray:
+    """One host copy of ``leaf`` that the caller owns.
+
+    On an accelerator ``np.asarray`` hands back the host buffer the
+    device-to-host transfer filled (the one ``copy_to_host_async``
+    started), with no second copy; the array caches it too, until its
+    buffers are deleted.  A CPU array's ``np.asarray`` is a view of the
+    live device buffer, which donation would take from under the ring,
+    so there one explicit copy is made.
+    """
+    if isinstance(leaf, jax.Array) and all(
+            d.platform != "cpu" for d in leaf.devices()):
+        return np.asarray(leaf)
+    return np.array(leaf)
+
+
 class DivergenceWatchdog:
     """Divergence detection + rollback to a last-good snapshot ring.
 
@@ -181,12 +197,28 @@ class DivergenceWatchdog:
     memory (at model scale one solver state is several GB, and
     ``depth`` device copies would not fit beside it).
 
+    Prefetch: ``prefetch(state)`` right after the chunk that produces
+    ``state`` is dispatched starts every leaf's device-to-host copy
+    (``copy_to_host_async``), so the transfer runs as soon as the chunk
+    finishes on the device, beside the training loop's evaluation of the
+    metric.  ``observe`` on that same state then only waits for it.
+    Either way each snapshot is exactly ONE host copy per leaf, owned by
+    the ring (``_host_copy``): the transfer's own buffer on an
+    accelerator, one explicit copy on CPU.  At an unhealthy point the
+    prefetched tree is dropped unread.  ``observe`` without a prefetch
+    copies synchronously, as before.
+
     Rollback does NOT rewind the round counter: the driver keeps
     advancing rounds, so the replayed trajectory diverges from the
     poisoned one (with deterministic per-round keys, rewinding would
     replay the identical divergence forever).  ``max_consecutive``
     rollbacks without an intervening healthy window raise — a watchdog
     that cannot re-stabilize should fail loudly, not spin.
+
+    Counters (``counters()``): ``snapshots`` taken, of them
+    ``prefetched`` (copy started before ``observe``), ``discarded``
+    prefetches (dropped at an unhealthy point), ``rollbacks`` and
+    ``snapshot_bytes`` (host bytes of all snapshots taken).
     """
 
     def __init__(self, depth: int = 3, blowup: float = 1e4,
@@ -197,7 +229,12 @@ class DivergenceWatchdog:
         self._ring = collections.deque(maxlen=depth)
         self._best = math.inf
         self._consecutive = 0
+        self._pending = None
+        self.snapshots = 0
+        self.prefetched = 0
+        self.discarded = 0
         self.rollbacks = 0
+        self.snapshot_bytes = 0
 
     def _bad(self, m: float) -> bool:
         if not math.isfinite(m):
@@ -205,15 +242,34 @@ class DivergenceWatchdog:
         return (math.isfinite(self._best)
                 and m > self.blowup * max(self._best, 1e-12))
 
+    def prefetch(self, state) -> None:
+        """Start the host copy of every leaf of ``state`` without
+        waiting for it; the next ``observe(state, ...)`` takes it."""
+        for leaf in jax.tree.leaves(state):
+            if isinstance(leaf, jax.Array):
+                leaf.copy_to_host_async()
+        self._pending = state
+
+    def has_prefetch(self, state) -> bool:
+        """Whether ``observe(state, ...)`` finds its copy started."""
+        return self._pending is state
+
     def observe(self, state, metric):
         """-> ``(state, rolled_back)``: the input state (now snapshotted)
         when healthy, else the last-good rollback state."""
         m = float(metric)
+        prefetched = self.has_prefetch(state)
+        self._pending = None
         if not self._bad(m):
             self._best = min(self._best, m)
-            self._ring.append(jax.tree.map(np.array, state))
+            snap = jax.tree.map(_host_copy, state)
+            self._ring.append(snap)
+            self.snapshots += 1
+            self.prefetched += prefetched
+            self.snapshot_bytes += sum(t.nbytes for t in jax.tree.leaves(snap))
             self._consecutive = 0
             return state, False
+        self.discarded += prefetched
         self.rollbacks += 1
         self._consecutive += 1
         if not self._ring:
@@ -226,6 +282,12 @@ class DivergenceWatchdog:
         # fresh device buffers: the caller's jitted chunk donates its
         # input, and the ring entry must survive a second rollback
         return jax.tree.map(jnp.asarray, self._ring[0]), True
+
+    def counters(self) -> dict:
+        """How often each path engaged (see the class docstring)."""
+        return {"snapshots": self.snapshots, "prefetched": self.prefetched,
+                "discarded": self.discarded, "rollbacks": self.rollbacks,
+                "snapshot_bytes": self.snapshot_bytes}
 
 
 def abstract_train_state(arch_def, cfg, solver):

@@ -141,8 +141,10 @@ def main(argv=None):
     ``pallas_in_round`` (a Pallas kernel in the compiled chunk),
     ``round_scopes`` (each top-level instruction of the compiled chunk
     that runs under an ``ltadmm.*`` named scope -> that scope),
-    ``losses`` (``[round, mean_loss]`` per logged chunk) and
-    ``telemetry`` (the counters with ``--telemetry``, else None).
+    ``losses`` (``[round, mean_loss]`` per logged chunk),
+    ``telemetry`` (the counters with ``--telemetry``, else None) and
+    ``watchdog`` (the divergence watchdog's ``counters()``, None with
+    ``--watchdog-blowup 0``).
     """
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen3-0.6b")
@@ -287,8 +289,10 @@ def main(argv=None):
     # One jitted dispatch per LOG POINT, not per round: scan over the
     # rounds of a chunk, with the solver state donated so XLA reuses the
     # (parameter-sized x edge-slots) state buffers in place across chunks.
-    @functools.partial(jax.jit, static_argnums=2, donate_argnums=0)
-    def run_chunk(state, first_round, n_rounds):
+    # The data is an argument, not a constant of the program, so every
+    # seed shares one executable (and its compilation-cache entry).
+    @functools.partial(jax.jit, static_argnums=3, donate_argnums=0)
+    def run_chunk(state, data, first_round, n_rounds):
         def body(st, r):
             return solver.step(st, data, jax.random.key(1000 + r)), None
 
@@ -301,13 +305,14 @@ def main(argv=None):
     summary = {"params": n_params, "reduced": cut, "compressor_impl": impl,
                "wire_bytes": solver.wire_bytes(params0), "compile_s": 0.0,
                "pallas_in_round": None, "round_scopes": None, "losses": [],
-               "telemetry": None}
+               "telemetry": None, "watchdog": None}
 
     def chunk_runner(state, first_round, n):
         """AOT-compile each chunk length once, timing it as set-up."""
         if n not in compiled:
             t0 = time.perf_counter()
-            compiled[n] = run_chunk.lower(state, first_round, n).compile()
+            compiled[n] = run_chunk.lower(state, data, first_round,
+                                          n).compile()
             summary["compile_s"] += time.perf_counter() - t0
             if summary["pallas_in_round"] is None:
                 text = compiled[n].as_text()
@@ -316,11 +321,15 @@ def main(argv=None):
                     text, "ltadmm.")
         return compiled[n]
 
-    def mean_loss(state):
+    # the log point's mean loss and consensus error, one program (run
+    # eagerly it dispatches hundreds of small ops and lowers the loss's
+    # scan again); the tokens are an argument, as in run_chunk
+    @jax.jit
+    def evaluate(state, tokens):
         x = solver.consensus_params(state)
         pbar = jax.tree.map(lambda t: jnp.mean(t, axis=0), x)
-        ls = jax.vmap(lambda d: loss(pbar, {"tokens": d}))(data["tokens"])
-        return float(jnp.mean(ls))
+        ls = jax.vmap(lambda d: loss(pbar, {"tokens": d}))(tokens)
+        return jnp.stack([jnp.mean(ls), consensus_error(x)])
 
     watchdog = (DivergenceWatchdog(blowup=args.watchdog_blowup)
                 if args.watchdog_blowup > 0 else None)
@@ -333,15 +342,20 @@ def main(argv=None):
             with tracer.span("train.chunk", first_round=r0, rounds=n,
                              cold=cold):
                 first = jnp.int32(done)
-                state = chunk_runner(state, first, n)(state, first)
+                state = chunk_runner(state, first, n)(state, data, first)
+                if watchdog is not None:
+                    # the snapshot's host copy runs beside the eval
+                    watchdog.prefetch(state)
                 if isinstance(tracer, trace.Tracer):
                     jax.block_until_ready(state)
             cold = False
             done += n
             with tracer.span("train.eval", first_round=r0):
-                ml = mean_loss(state)
+                ml, cerr = (float(v) for v in
+                            np.asarray(evaluate(state, data["tokens"])))
             if watchdog is not None:
-                with tracer.span("train.watchdog", first_round=r0):
+                with tracer.span("train.watchdog", first_round=r0,
+                                 prefetched=watchdog.has_prefetch(state)):
                     state, rolled_back = watchdog.observe(state, ml)
                 if rolled_back:
                     # skip-ahead: restore last-good state but keep
@@ -359,9 +373,7 @@ def main(argv=None):
                 print(json.dumps({
                     "round": done - 1,
                     "mean_loss": round(ml, 4),
-                    "consensus_err": float(
-                        consensus_error(solver.consensus_params(state))
-                    ),
+                    "consensus_err": cerr,
                     "wall_s": round(time.time() - t_start, 1),
                 }))
             if (args.checkpoint_every and done < args.rounds
@@ -387,6 +399,8 @@ def main(argv=None):
             print(f"# checkpoint written to {args.checkpoint}")
     finally:
         tracer.close()
+    if watchdog is not None:
+        summary["watchdog"] = watchdog.counters()
     return summary
 
 

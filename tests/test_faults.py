@@ -340,3 +340,86 @@ def test_watchdog_snapshots_live_on_host():
     assert rb and all(isinstance(t, jax.Array) for t in jax.tree.leaves(out))
     assert out["k"].dtype == jnp.uint32
     np.testing.assert_array_equal(np.asarray(out["x"]), np.arange(4.0))
+
+
+def test_watchdog_prefetched_snapshot_is_one_owned_host_copy():
+    """A prefetched snapshot is an owned host array equal to the state,
+    and it outlives a real donation of the state it was taken from."""
+    wd = DivergenceWatchdog(depth=2, blowup=10.0)
+    state = {"x": jnp.arange(6.0) * 0.5,
+             "k": jnp.arange(3, dtype=jnp.uint32)}
+    want = jax.tree.map(np.array, state)
+    wd.prefetch(state)
+    assert wd.has_prefetch(state)
+    out, rb = wd.observe(state, 1.0)
+    assert out is state and not rb
+    snap = wd._ring[0]
+    for name, t in snap.items():
+        assert isinstance(t, np.ndarray) and t.flags.owndata
+        assert t.ctypes.data != state[name].unsafe_buffer_pointer()
+        np.testing.assert_array_equal(t, want[name])
+    step = jax.jit(lambda s: jax.tree.map(lambda t: t * 3, s),
+                   donate_argnums=0)
+    jax.block_until_ready(step(state))
+    assert state["x"].is_deleted()
+    out, rb = wd.observe({"x": jnp.zeros(6), "k": jnp.zeros(3, jnp.uint32)},
+                         float("nan"))
+    assert rb
+    for name, t in out.items():
+        assert t.dtype == want[name].dtype
+        np.testing.assert_array_equal(np.asarray(t), want[name])
+    assert wd.counters() == {"snapshots": 1, "prefetched": 1,
+                             "discarded": 0, "rollbacks": 1,
+                             "snapshot_bytes": 6 * 4 + 3 * 4}
+
+
+def test_watchdog_prefetch_dropped_at_unhealthy_point():
+    """An unhealthy metric drops the prefetched copy unread and rolls
+    back to the oldest healthy entry."""
+    wd = DivergenceWatchdog(depth=2, blowup=10.0)
+    wd.observe({"x": jnp.asarray([1.0])}, 1.0)
+    wd.prefetch(s2 := {"x": jnp.asarray([2.0])})
+    wd.observe(s2, 0.5)
+    bad = {"x": jnp.asarray([jnp.nan])}
+    wd.prefetch(bad)
+    out, rb = wd.observe(bad, float("nan"))
+    assert rb and float(out["x"][0]) == 1.0
+    assert not wd.has_prefetch(bad) and len(wd._ring) == 2
+    c = wd.counters()
+    assert (c["snapshots"], c["prefetched"], c["discarded"],
+            c["rollbacks"]) == (2, 1, 1, 1)
+
+
+def test_watchdog_prefetch_of_another_state_is_not_taken():
+    """``observe`` takes a prefetch only for the state it was started
+    on; any other state is copied synchronously, once."""
+    wd = DivergenceWatchdog(blowup=10.0)
+    wd.prefetch({"x": jnp.asarray([1.0])})
+    other = {"x": jnp.asarray([3.0])}
+    assert not wd.has_prefetch(other)
+    wd.observe(other, 1.0)
+    np.testing.assert_array_equal(wd._ring[0]["x"], [3.0])
+    assert wd.counters()["snapshots"] == 1
+    assert wd.counters()["prefetched"] == 0
+
+
+@pytest.mark.parametrize("blowup", [1e4, 0.0])
+def test_train_main_reports_watchdog_counters(blowup):
+    """With the watchdog on, every log point of a healthy run is one
+    prefetched snapshot; ``--watchdog-blowup 0`` reports None."""
+    from repro.launch import train
+
+    summary = train.main([
+        "--smoke", "--agents", "2", "--topology", "complete",
+        "--compressor", "identity", "--rounds", "3", "--log-every", "1",
+        "--seq-len", "16", "--m-local", "2", "--tau", "1",
+        "--batch-size", "1", "--watchdog-blowup", str(blowup)])
+    wd = summary["watchdog"]
+    if blowup == 0:
+        assert wd is None
+        return
+    assert wd["snapshots"] == len(summary["losses"]) == 3
+    assert wd["prefetched"] == wd["snapshots"]
+    assert wd["discarded"] == wd["rollbacks"] == 0
+    assert wd["snapshot_bytes"] > 0
+    assert wd["snapshot_bytes"] % wd["snapshots"] == 0

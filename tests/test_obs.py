@@ -361,6 +361,43 @@ def test_train_main_returns_round_scopes():
         "ltadmm.update"}
 
 
+_SEEDS_SCRIPT = """
+import collections, json, jax
+from repro.launch import train
+seen = collections.Counter()
+jax.monitoring.register_event_duration_secs_listener(
+    lambda event, duration, **kw: seen.update([event]))
+out = []
+for seed in (3, 2147483901):
+    seen.clear()
+    train.main(["--smoke", "--agents", "2", "--topology", "complete",
+                "--rounds", "1", "--seq-len", "16", "--m-local", "2",
+                "--tau", "1", "--batch-size", "1", "--seed", str(seed)])
+    out.append([seen["/jax/core/compile/backend_compile_duration"],
+                seen["/jax/compilation_cache/cache_retrieval_time_sec"]])
+print(json.dumps(out))
+"""
+
+
+def test_train_main_programs_are_shared_across_seeds(tmp_path):
+    """The round and the log point's evaluation take the seed's data as
+    an argument: a second seed finds both in the persistent compilation
+    cache and compiles nothing (a fresh process, so the cache is on)."""
+    import os
+    import subprocess
+    import sys
+
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               JAX_COMPILATION_CACHE_DIR=str(tmp_path),
+               JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS="0")
+    res = subprocess.run([sys.executable, "-c", _SEEDS_SCRIPT], env=env,
+                         capture_output=True, text=True, timeout=600)
+    assert res.returncode == 0, res.stderr[-2000:]
+    (req0, hit0), (req1, hit1) = json.loads(res.stdout.splitlines()[-1])
+    assert hit0 == 0 and req0 >= 2
+    assert req1 >= 2 and hit1 == req1
+
+
 def test_timeit_smoke():
     f = jax.jit(lambda x: x + 1)
     us = trace.timeit(f, jnp.zeros((8,)), iters=2)
