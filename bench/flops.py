@@ -1,11 +1,10 @@
 """Operations and bytes the training round needs, from the configuration
 and the mix alone (never from the compiled program).
 
-Model FLOPs follow the PaLM convention (Chowdhery et al. 2022, appendix
-B): ``6 * P + 12 * L * d_q * T`` per token, where ``P`` counts every
-matrix-product parameter, the tied unembedding included, ``d_q`` is the
-query width (heads times head size) and ``T`` the sequence length.
-Recomputation (remat) is not counted.
+The plane size (``param_count``) and the model FLOPs per token
+(``flops_per_token``) come from the configuration's plain reference
+module, the one its ``reference`` key names (``bench/ref``): a
+configuration with a block of its own brings its own counts.
 
 A round of LT-ADMM with the SVRG anchor on ``A`` agents processes
 ``A * (m_local + 2 * tau * batch_size) * T`` tokens: one anchor pass
@@ -14,32 +13,16 @@ over the agent's ``m_local`` sequences, then two minibatch gradients
 """
 from __future__ import annotations
 
-
-def _attn_params(c):
-    d, h, kh, dh = (c["hidden_size"], c["num_attention_heads"],
-                    c["num_key_value_heads"], c["head_dim"])
-    return d * h * dh * 2 + d * kh * dh * 2  # wq, wo, wk, wv
-
-
-def matmul_params(c) -> int:
-    """Parameters that enter a matrix product, tied unembedding once."""
-    per_layer = _attn_params(c) + 3 * c["hidden_size"] * c["intermediate_size"]
-    return c["num_hidden_layers"] * per_layer + c["vocab_size"] * c["hidden_size"]
+from bench import ref
 
 
 def param_count(c) -> int:
-    """Every parameter of the model: matrix weights and norm scales."""
-    n = matmul_params(c)
-    if c["norm"] == "rms":
-        n += (2 * c["num_hidden_layers"] + 1) * c["hidden_size"]
-    if c["qk_norm"]:
-        n += 2 * c["num_hidden_layers"] * c["head_dim"]
-    return n
+    """Every parameter of the model: the length of the packed plane."""
+    return ref.module(c).param_count(c)
 
 
 def flops_per_token(c, seq_len) -> int:
-    d_q = c["num_attention_heads"] * c["head_dim"]
-    return 6 * matmul_params(c) + 12 * c["num_hidden_layers"] * d_q * seq_len
+    return ref.module(c).flops_per_token(c, seq_len)
 
 
 def tokens_per_round(train) -> int:

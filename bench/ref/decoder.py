@@ -16,6 +16,13 @@ Weights are drawn from the seed in the order and with the scales the
 system under test documents for its parameter specs: one key per leaf,
 leaves in sorted-path order, normal draws scaled by the fan-in of the
 first non-layer axis (``0.02`` for the embedding), norm scales at one.
+
+Counts (``bench/flops.py`` reads them): ``param_count`` is every weight
+of the packed plane; model FLOPs follow the PaLM convention (Chowdhery
+et al. 2022, appendix B): ``6 * P + 12 * L * d_q * T`` per token, where
+``P`` counts every matrix-product parameter, the tied unembedding
+included once, ``d_q`` is the query width (heads times head size) and
+``T`` the sequence length.  Recomputation (remat) is not counted.
 """
 from __future__ import annotations
 
@@ -23,6 +30,33 @@ import math
 
 import jax
 import jax.numpy as jnp
+
+
+def _attn_params(c):
+    d, h, kh, dh = (c["hidden_size"], c["num_attention_heads"],
+                    c["num_key_value_heads"], c["head_dim"])
+    return d * h * dh * 2 + d * kh * dh * 2  # wq, wo, wk, wv
+
+
+def matmul_params(c) -> int:
+    """Parameters that enter a matrix product, tied unembedding once."""
+    per_layer = _attn_params(c) + 3 * c["hidden_size"] * c["intermediate_size"]
+    return c["num_hidden_layers"] * per_layer + c["vocab_size"] * c["hidden_size"]
+
+
+def param_count(c) -> int:
+    """Every parameter of the model: matrix weights and norm scales."""
+    n = matmul_params(c)
+    if c["norm"] == "rms":
+        n += (2 * c["num_hidden_layers"] + 1) * c["hidden_size"]
+    if c["qk_norm"]:
+        n += 2 * c["num_hidden_layers"] * c["head_dim"]
+    return n
+
+
+def flops_per_token(c, seq_len) -> int:
+    d_q = c["num_attention_heads"] * c["head_dim"]
+    return 6 * matmul_params(c) + 12 * c["num_hidden_layers"] * d_q * seq_len
 
 
 def leaf_specs(cfg):

@@ -20,8 +20,12 @@ system under test, and importing nothing of it:
 
 ``run`` returns the consensus mean after the logged rounds, the mean
 loss and the consensus error at each log point, and the first round's
-anchor gradient per leaf.  Each agent's local phase is one jitted call,
-so the reference holds one agent's activations at a time.
+anchor gradient per leaf.  Agent i's vectors and its local phase live on
+device ``i % len(devices)`` of the devices it is given, and each message
+is moved once, to the device of the agent that receives it.  Each
+agent's local phase is one jitted call that sums its anchor gradient one
+sequence at a time, so a device holds its agents' state, one agent's
+activations and a few planes of gradient at a time.
 """
 from __future__ import annotations
 
@@ -119,9 +123,14 @@ def make_tokens(seed, agents, m_local, seq_len, vocab, heterogeneity):
 
 
 def neighbours(topology, agents):
-    """Sorted neighbour list of every agent of an undirected graph."""
+    """Sorted neighbour list of every agent of an undirected graph:
+    ``complete``, or ``ring`` (agent i beside (i - 1) mod A and (i + 1)
+    mod A: degree 2, or 1 for two agents)."""
     if topology == "complete":
         return [[j for j in range(agents) if j != i] for i in range(agents)]
+    if topology == "ring":
+        return [sorted({(i - 1) % agents, (i + 1) % agents})
+                for i in range(agents)]
     raise ValueError(f"reference has no topology {topology!r}")
 
 
@@ -157,14 +166,16 @@ class Reference:
     ``beta``, ``gamma``, ``r``, ``eta``, ``bits``, ``tau``,
     ``batch_size``, ``m_local``, ``seq_len``, ``heterogeneity``).
     ``plane_dtype``/``compute_dtype`` set the precision of the solver
-    state and of the model's products.  ``fault`` plants one of the
-    faults a round can have: ``"half_batch"`` (anchor gradient over
-    the first half of the agent's sequences), ``"no_exchange"`` (every
-    received message reads as zero).
+    state and of the model's products.  ``devices``: where the agents
+    live, agent i on ``devices[i % len(devices)]`` (the first device by
+    default).  ``fault`` plants one of the faults a round can have:
+    ``"half_batch"`` (anchor gradient over the first half of the
+    agent's sequences), ``"no_exchange"`` (every received message reads
+    as zero).
     """
 
     def __init__(self, model, train, *, net=decoder, plane_dtype=jnp.float32,
-                 compute_dtype=jnp.float32, fault=None):
+                 compute_dtype=jnp.float32, devices=None, fault=None):
         if train["eta"] != 1.0:
             raise ValueError("reference implements eta = 1 only")
         if fault not in (None, "half_batch", "no_exchange"):
@@ -172,11 +183,15 @@ class Reference:
         self.model, self.train, self.fault = model, train, fault
         self.pdt, self.cdt = plane_dtype, compute_dtype
         self.nbrs = neighbours(train["topology"], train["agents"])
+        self.devices = list(devices or jax.devices()[:1])
         self.net = net
         self._grad = jax.jit(jax.grad(
             lambda w, t: net.loss(model, w, t, compute_dtype)))
         self._loss = jax.jit(
             lambda w, t: net.loss(model, w, t, compute_dtype))
+
+    def device(self, agent):
+        return self.devices[agent % len(self.devices)]
 
     # one agent's local phase: anchor, then tau variance-reduced steps
     @functools.partial(jax.jit, static_argnums=(0, 1))
@@ -189,24 +204,30 @@ class Reference:
             return plane.pack(self._grad(plane.unpack(w_flat), seqs)).astype(
                 self.pdt)
 
-        per_seq = jax.lax.map(lambda s: seq_grad(x, s[None]),
-                              tokens[:anchor_rows])
-        anchor_grad = jnp.mean(per_seq.astype(jnp.float32), 0).astype(self.pdt)
+        def add_seq(total, seq):
+            return total + seq_grad(x, seq[None]).astype(jnp.float32), None
+
+        total, _ = jax.lax.scan(add_seq, jnp.zeros(plane.n, jnp.float32),
+                                tokens[:anchor_rows])
+        anchor_grad = (total / anchor_rows).astype(self.pdt)
         kb = jax.random.fold_in(jax.random.fold_in(round_key, SALT_BATCH), aid)
 
         def body(phi, t):
             idx = jax.random.randint(jax.random.fold_in(kb, t),
                                      (tr["batch_size"],), 0, m)
-            g_phi = seq_grad(phi, tokens[idx])
-            if anchor_rows == m:
-                g_anc = jnp.mean(per_seq[idx].astype(jnp.float32), 0)
-            else:
-                g_anc = seq_grad(x, tokens[idx]).astype(jnp.float32)
-            g = (g_phi - g_anc.astype(self.pdt)) + anchor_grad
+            g = (seq_grad(phi, tokens[idx]) - seq_grad(x, tokens[idx])) \
+                + anchor_grad
             return phi - tr["gamma"] * g - corr, None
 
         phi, _ = jax.lax.scan(body, x, jnp.arange(tr["tau"]))
         return phi, anchor_grad
+
+    def _mean(self, vectors):
+        """Float32 mean of one vector per agent, on the first device."""
+        first = self.devices[0]
+        total = sum(jax.device_put(v, first).astype(jnp.float32)
+                    for v in vectors)
+        return total / len(vectors)
 
     def _mean_loss(self, plane, xbar_flat, tokens):
         w = plane.unpack(xbar_flat)
@@ -221,70 +242,91 @@ class Reference:
         """Train ``rounds`` rounds from ``seed``; returns ``weights0``,
         ``xbar`` (consensus mean, per leaf), ``losses`` and ``consensus``
         (one per log point) and ``grad0`` (mean first anchor gradient,
-        per leaf)."""
-        tr, mdl = self.train, self.model
+        per leaf).
+
+        Every vector of agent i, and each message it receives, is on
+        ``self.device(i)``; a state vector is replaced as soon as its
+        new value is made, and each message is dropped once applied.
+        Each device runs one local phase at a time, and each sender's
+        messages and each agent's z update are finished before the next
+        are dispatched, so that no device holds the temporaries of work
+        queued ahead."""
+        tr, mdl, nbrs = self.train, self.model, self.nbrs
         A = tr["agents"]
+        dev = [self.device(i) for i in range(A)]
         weights0 = self.net.init_weights(mdl, seed + 1)
-        tokens = make_tokens(seed, A, tr["m_local"], tr["seq_len"],
-                             mdl["vocab_size"], tr["heterogeneity"])
+        all_tokens = make_tokens(seed, A, tr["m_local"], tr["seq_len"],
+                                 mdl["vocab_size"], tr["heterogeneity"])
+        tokens = [jax.device_put(all_tokens[i], dev[i]) for i in range(A)]
         plane = Plane(weights0)
         x0 = plane.pack(weights0).astype(self.pdt)
-        x = [x0] * A
-        x_hat = [x0] * A
-        zero = jnp.zeros_like(x0)
-        z = [{j: zero for j in self.nbrs[i]} for i in range(A)]
-        s = [{j: zero for j in self.nbrs[i]} for i in range(A)]
-        s_tilde = [{j: zero for j in self.nbrs[i]} for i in range(A)]
-        x_hat_nbr = [{j: x0 for j in self.nbrs[i]} for i in range(A)]
+        weights0 = jax.device_get(weights0)
+        x = [jax.device_put(x0, dev[i]) for i in range(A)]
+        x_hat = list(x)
+        zero = [jnp.zeros_like(xi) for xi in x]
+        z = [{j: zero[i] for j in nbrs[i]} for i in range(A)]
+        s = [{j: zero[i] for j in nbrs[i]} for i in range(A)]
+        s_tilde = [{j: zero[i] for j in nbrs[i]} for i in range(A)]
+        x_hat_nbr = [{j: x[i] for j in nbrs[i]} for i in range(A)]
+        del x0, zero
         bits, rrho = tr["bits"], tr["r"] * tr["rho"]
+        lost = self.fault == "no_exchange"
         losses, consensus, grad0 = [], [], None
         for rnd in range(rounds):
             key = jax.random.key(ROUND_KEY_BASE + rnd)
             kx = jax.random.fold_in(key, SALT_X)
             kz = jax.random.fold_in(key, SALT_Z)
-            x_new, anchors = [], []
+            anchors, running = [], {}
             for i in range(A):
+                if dev[i] in running:
+                    running[dev[i]].block_until_ready()
                 z_sum = functools.reduce(jnp.add, z[i].values())
                 corr = tr["beta"] * (
-                    tr["r"] ** 2 * tr["rho"] * len(self.nbrs[i]) * x[i]
+                    tr["r"] ** 2 * tr["rho"] * len(nbrs[i]) * x[i]
                     - tr["r"] * z_sum)
-                phi, g0 = self._local(plane, x[i], corr, tokens[i], key, i)
-                x_new.append(phi)
-                anchors.append(g0)
+                x[i], g0 = self._local(plane, x[i], corr, tokens[i], key, i)
+                running[dev[i]] = x[i]
+                if grad0 is None:
+                    anchors.append(g0)
             if grad0 is None:
-                grad0 = plane.unpack(
-                    sum(g.astype(jnp.float32) for g in anchors) / A)
+                grad0 = plane.unpack(self._mean(anchors))
+                del anchors
             # x-messages: one broadcast per sender, error feedback on x_hat
-            dx = []
             for i in range(A):
                 q, sc = quantize(*message_seed(kx, i, BROADCAST),
-                                 x_new[i] - x_hat[i], bits=bits)
-                dx.append(dequantize(q, sc, bits, self.pdt))
-            x_hat_new = [x_hat[i] + dx[i] for i in range(A)]
+                                 x[i] - x_hat[i], bits=bits)
+                dx = dequantize(q, sc, bits, self.pdt)
+                x_hat[i] = x_hat[i] + dx
+                for j in nbrs[i]:
+                    if not lost:
+                        x_hat_nbr[j][i] = (x_hat_nbr[j][i]
+                                           + jax.device_put(dx, dev[j]))
+                jax.block_until_ready(
+                    (x_hat[i], [x_hat_nbr[j][i] for j in nbrs[i]]))
             # z-messages: one per directed edge, error feedback on s
-            rec = {}
             for i in range(A):
-                for j in self.nbrs[i]:
+                for j in nbrs[i]:
                     q, sc = quantize(*message_seed(kz, i, j),
                                      z[i][j] - s[i][j], bits=bits)
-                    rec[i, j] = dequantize(q, sc, bits, self.pdt)
-            z_hat_own = [{j: s[i][j] + rec[i, j] for j in self.nbrs[i]}
-                         for i in range(A)]
-            lost = self.fault == "no_exchange"
-            x_hat_nbr = [{j: x_hat_nbr[i][j] + (zero if lost else dx[j])
-                          for j in self.nbrs[i]} for i in range(A)]
-            s_tilde = [{j: s_tilde[i][j] + (zero if lost else rec[j, i])
-                        for j in self.nbrs[i]} for i in range(A)]
-            z = [{j: 0.5 * (z_hat_own[i][j] - s_tilde[i][j])
-                  + rrho * x_new[i] - rrho * (x_hat_new[i] - x_hat_nbr[i][j])
-                  for j in self.nbrs[i]} for i in range(A)]
-            s, x, x_hat = z_hat_own, x_new, x_hat_new
+                    rec = dequantize(q, sc, bits, self.pdt)
+                    s[i][j] = s[i][j] + rec  # the sender's z_hat
+                    if not lost:
+                        s_tilde[j][i] = (s_tilde[j][i]
+                                         + jax.device_put(rec, dev[j]))
+                jax.block_until_ready(
+                    (s[i], [s_tilde[j][i] for j in nbrs[i]]))
+            for i in range(A):
+                for j in nbrs[i]:
+                    z[i][j] = (0.5 * (s[i][j] - s_tilde[i][j]) + rrho * x[i]
+                               - rrho * (x_hat[i] - x_hat_nbr[i][j]))
+                jax.block_until_ready(z[i])
             if (rnd + 1) % log_every == 0:
-                xs = [xi.astype(jnp.float32) for xi in x]
-                xbar = sum(xs) / A
-                losses.append(self._mean_loss(plane, xbar, tokens))
-                consensus.append(float(sum(
-                    jnp.sum(jnp.square(xi - xbar)) for xi in xs)))
-        xbar = sum(xi.astype(jnp.float32) for xi in x) / A
-        return {"weights0": weights0, "xbar": plane.unpack(xbar),
+                xbar = self._mean(x)
+                losses.append(self._mean_loss(plane, xbar, all_tokens))
+                consensus.append(sum(
+                    float(jnp.sum(jnp.square(
+                        x[i].astype(jnp.float32)
+                        - jax.device_put(xbar, dev[i]))))
+                    for i in range(A)))
+        return {"weights0": weights0, "xbar": plane.unpack(self._mean(x)),
                 "losses": losses, "consensus": consensus, "grad0": grad0}

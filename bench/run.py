@@ -24,9 +24,10 @@ cell's flags and ``--seed``:
 
 Set-up is everything up to the window's first line.  With ``--trace
 1`` the ``jax.profiler`` capture covers the window alone and the
-per-layer metrics are read from it.  After the window the device's
-peak memory is read, the program's state is dropped, and the reference
-follows the checked rounds (``bench/check.py`` says what is compared).
+per-layer metrics are read from it.  After the window the peak memory
+of the fullest of the cell's chips is read, the program's state is
+dropped, and the reference follows the checked rounds with its agents
+over the cell's chips (``bench/check.py`` says what is compared).
 
 The last line of standard output is one JSON object; the last lines of
 standard error are the compared numbers beside their limits.
@@ -110,6 +111,13 @@ def program_argv(spec, seed, rounds, extra=()):
     return (flags(cfg["program"]) + flags(cfg["solver"])
             + flags(mix["program"])
             + ["--seed", str(seed), "--rounds", str(rounds), *extra])
+
+
+def checked_rounds(spec):
+    """``(log_every, rounds)``: the rounds of the first log points that
+    cover ``CHECK_ROUNDS`` rounds, which the reference follows."""
+    every = int(spec["mix"]["program"]["log-every"])
+    return every, every * math.ceil(CHECK_ROUNDS / every)
 
 
 def reference_train(spec) -> dict:
@@ -223,6 +231,14 @@ def find_device(chips, peaks):
     return devs
 
 
+def max_peak(devices):
+    """Peak bytes in use on the fullest of ``devices``, or None where the
+    backend reports none."""
+    peaks = [(d.memory_stats() or {}).get("peak_bytes_in_use")
+             for d in devices]
+    return max((p for p in peaks if p is not None), default=None)
+
+
 def _metric(name):
     return importlib.import_module(f"bench.metrics.{name}")
 
@@ -234,17 +250,44 @@ def _xplane(directory):
     return found[-1]
 
 
+def reference_out(spec, seed, devices):
+    """The float32 reference over the checked rounds from ``seed``, its
+    agent i on ``devices[i % len(devices)]``, with ``wire_per_round``
+    (the busiest agent's quantized message bytes in one round)."""
+    import jax
+    import numpy as np
+
+    from bench import ref
+    from bench.ref import ltadmm
+
+    every, checked = checked_rounds(spec)
+    train = reference_train(spec)
+    reference = ltadmm.Reference(spec["config"], train,
+                                 net=ref.module(spec["config"]),
+                                 devices=devices)
+    t_ref = time.perf_counter()
+    with jax.default_matmul_precision("highest"):
+        out = reference.run(seed, checked, every)
+    nbrs = ltadmm.neighbours(train["topology"], train["agents"])
+    n = sum(int(np.prod(v.shape)) for v in out["weights0"].values())
+    out["wire_per_round"] = (max(len(x) for x in nbrs) * 2
+                             * ltadmm.message_bytes(n, train["bits"]))
+    log(f"reference took {time.perf_counter() - t_ref:.1f} s; peak of "
+        f"each chip since the start {[max_peak([d]) for d in devices]} B")
+    return out
+
+
 def run_cell(spec, seed, seconds, trace, devices, peaks=None):
-    """One run of the cell; -> (result dict, checks)."""
+    """One run of the cell on ``devices[:chips]``; -> (result dict,
+    checks)."""
     import jax
     import numpy as np
 
     from bench import check
-    from bench.ref import ltadmm
 
-    mix = spec["mix"]["program"]
-    every = int(mix["log-every"])
-    checked = every * math.ceil(CHECK_ROUNDS / every)
+    chips = devices[:int(spec["cell"]["chips"])]
+
+    every, checked = checked_rounds(spec)
     with tempfile.TemporaryDirectory(prefix="bench.") as tmp:
         ckpt = str(Path(tmp) / "checked")
         log(f"checked call: {checked} rounds")
@@ -267,8 +310,7 @@ def run_cell(spec, seed, seconds, trace, devices, peaks=None):
         sum_b, lines_b = drive(program_argv(spec, seed, every * (1 + n_win)),
                                window.on_line)
         chunks_b = chunk_lines(lines_b)
-        stats = devices[0].memory_stats() or {}
-        peak = stats.get("peak_bytes_in_use")
+        peak = max_peak(chips)
         rounds = n_win * every
         ctx = types.SimpleNamespace(
             setup_s=window.t0 - T_START,
@@ -280,6 +322,8 @@ def run_cell(spec, seed, seconds, trace, devices, peaks=None):
             model=spec["config"],
             train=reference_train(spec),
             peaks=peaks,
+            round_scopes=sum_b.get("round_scopes"),
+            capture=None,
             reduction=None,
         )
         breakdown = None
@@ -287,8 +331,8 @@ def run_cell(spec, seed, seconds, trace, devices, peaks=None):
             from bench import trace_reduce
 
             ctx.window_s = window.trace_t1 - window.trace_t0
-            ctx.reduction = trace_reduce.reduce(
-                _xplane(Path(tmp) / "profile"), ctx.window_s)
+            ctx.capture = _xplane(Path(tmp) / "profile")
+            ctx.reduction = trace_reduce.reduce(ctx.capture, ctx.window_s)
             breakdown = {
                 "device_ops": trace_reduce.top_ops(ctx.reduction),
                 "idle_gaps": [list(g) for g in ctx.reduction.gaps],
@@ -308,18 +352,7 @@ def run_cell(spec, seed, seconds, trace, devices, peaks=None):
     gc.collect()
     live = sum(a.nbytes for a in jax.live_arrays())
     log(f"reference: {checked} rounds; {live} B live on the device before it")
-    train = reference_train(spec)
-    net = importlib.import_module(
-        "bench.ref." + Path(spec["config"]["reference"]).stem)
-    ref = ltadmm.Reference(spec["config"], train, net=net)
-    t_ref = time.perf_counter()
-    with jax.default_matmul_precision("highest"):
-        out = ref.run(seed, checked, every)
-    nbrs = ltadmm.neighbours(train["topology"], train["agents"])
-    n = sum(int(np.prod(v.shape)) for v in out["weights0"].values())
-    out["wire_per_round"] = (max(len(x) for x in nbrs) * 2
-                             * ltadmm.message_bytes(n, train["bits"]))
-    log(f"reference took {time.perf_counter() - t_ref:.1f} s")
+    out = reference_out(spec, seed, chips)
     tel = sum_b["telemetry"] or {"tx_bytes": [0], "rounds": 0}
     prog = {
         "losses": [loss for _, loss in sum_a["losses"]],

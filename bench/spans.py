@@ -15,8 +15,10 @@ The program marks its work in two ways that reach the capture:
   ``train.main`` returns as ``round_scopes``, parsed from the compiled
   text).
 
-``reduce(path, window_s)`` reads, on the first chip that ran anything
-(as ``trace_reduce`` does for its gaps):
+``reduce(path, window_s)`` reads each chip that ran anything
+(``chips``, the count ``trace_reduce`` gives as ``Reduction.chips``)
+and gives one chip's numbers: every time below is summed over those
+chips and divided by their number.
 
 * ``module_s``: device seconds of the round program (``jit_run_chunk``),
   and ``module_idle_s`` the stretches inside it in which no op ran;
@@ -25,32 +27,27 @@ The program marks its work in two ways that reach the capture:
   (a scan's ``while``) are left out, as their bodies' ops count, and
   ``control_s`` is the time in which only a container ran (the loops'
   own bookkeeping).  The phases, ``unscoped_s``, ``control_s`` and
-  ``module_idle_s`` add up to ``module_s`` when no two ops overlap;
+  ``module_idle_s`` add up to ``module_s`` when no two ops of a chip
+  overlap;
 * ``idle_s``: the window less the union of the op intervals;
 * ``span_idle_s``: the idle time inside the spans of each name, and
   ``unattributed_idle_s`` the idle time inside no ``train.*`` span.
   Idle is time the chip ran nothing, not a span's length;
-* ``span_rows`` (each span's name, ``first_round``, wall and idle time)
-  and ``module_runs_s`` (each run of the round program), for a split
-  round by round.
+* ``span_rows`` (each span's name, ``first_round``, wall time and idle
+  time) and ``module_runs_s`` (each run of the round program, chip by
+  chip, as measured), for a split round by round.
 
 A capture of a program without the spans or scopes reads empty
-``span_idle_s`` or ``phase_s``, and the readers return None.
-
-The readers get no capture path from the harness, so ``for_ctx`` takes
-the newest capture under the temporary directories that ``bench/run.py``
-profiles into, and uses it only if its busy time is the one the
-harness's own reduction read.
+``span_idle_s`` or ``phase_s``, and the readers return None.  The
+readers find the capture at ``ctx.capture``, where ``bench/run.py``
+profiled the window.
 """
 from __future__ import annotations
 
 import bisect
 import dataclasses
 import functools
-import glob
-import os
 import re
-import tempfile
 
 from bench import trace_reduce
 
@@ -58,15 +55,11 @@ MODULE = "jit_run_chunk"
 SPAN_PREFIX = "train."
 # a phase is the first ``ltadmm.<name>`` component of an op's op_name
 SCOPE_RE = re.compile(r"(?<![\w.])ltadmm\.\w+")
-# where bench/run.py's Window profiles to (its TemporaryDirectory)
-CAPTURES = os.path.join("bench.*", "profile", "plugins", "profile", "*",
-                        "*.xplane.pb")
 
 
 @dataclasses.dataclass
 class Spans:
     window_s: float
-    busy_s: float
     module_s: float
     module_idle_s: float
     phase_s: dict
@@ -77,6 +70,7 @@ class Spans:
     unattributed_idle_s: float
     span_rows: list  # [name, first_round, wall s, idle s] per span
     module_runs_s: list  # device seconds of each round-program run
+    chips: int
 
 
 # ---- op metadata: the capture's protobuf, read directly --------------------
@@ -198,31 +192,11 @@ class Idle:
         return max(0.0, self._upto(b) - self._upto(a))
 
 
-def reduce(path, window_s: float) -> Spans:
-    from jax.profiler import ProfileData
-
-    scopes = op_scopes(path)
-    data = ProfileData.from_file(str(path))
-    spans, chip, busy = [], None, []
-    for plane in data.planes:
-        if plane.name == trace_reduce.HOST_PLANE:
-            spans += [(e.start_ns, e.end_ns, e.name,
-                       dict(e.stats).get("first_round"))
-                      for line in plane.lines for e in line.events
-                      if e.name.startswith(SPAN_PREFIX)]
-            continue
-        if not plane.name.startswith(trace_reduce.DEVICE_PREFIX):
-            continue
-        lines = {line.name: list(line.events) for line in plane.lines}
-        ops = lines.get(trace_reduce.OPS_LINE, [])
-        if ops:
-            busy.append(trace_reduce._union_s(
-                [(e.start_ns, e.end_ns) for e in ops]))
-            if chip is None:
-                chip = ops, lines.get(trace_reduce.MODULES_LINE, [])
-    ops, modules = chip or ([], [])
+def _chip(ops, modules, scopes, spans, window_s):
+    """One chip's numbers: ``Spans``'s fields but ``window_s`` and
+    ``chips``, with ``idle_rows`` the idle seconds inside
+    each of ``spans`` (sorted ``(start, end, ...)``)."""
     idle = Idle([(e.start_ns, e.end_ns) for e in ops], window_s * 1e9)
-
     rounds = sorted((e.start_ns, e.end_ns) for e in modules
                     if e.name.split("(")[0] == MODULE)
     starts = [s for s, _ in rounds]
@@ -241,57 +215,90 @@ def reduce(path, window_s: float) -> Spans:
             unscoped += e.duration_ns / 1e9
         else:
             phase_s[scope] = phase_s.get(scope, 0.0) + e.duration_ns / 1e9
-
-    span_idle, rows = {}, []
-    for s, e, name, first_round in sorted(spans):
-        rows.append([name, first_round, (e - s) / 1e9,
-                     idle.between(s, e) / 1e9])
-        span_idle[name] = span_idle.get(name, 0.0) + rows[-1][3]
     covered = sum(idle.between(s, e) for s, e in
                   trace_reduce._merged([sp[:2] for sp in spans]))
+    return {
+        "module_s": sum(e - s for s, e in rounds) / 1e9,
+        "module_idle_s": sum(idle.between(s, e) for s, e in rounds) / 1e9,
+        "phase_s": phase_s,
+        "unscoped_s": unscoped,
+        "control_s": (trace_reduce._union_s(spanned)
+                      - trace_reduce._union_s(leaves)),
+        "idle_s": idle.total_ns / 1e9,
+        "unattributed_idle_s": (idle.total_ns - covered) / 1e9,
+        "idle_rows": [idle.between(s, e) / 1e9 for s, e, *_ in spans],
+        "module_runs_s": [(e - s) / 1e9 for s, e in rounds],
+    }
+
+
+def reduce(path, window_s: float) -> Spans:
+    from jax.profiler import ProfileData
+
+    scopes = op_scopes(path)
+    data = ProfileData.from_file(str(path))
+    spans, chips = [], []
+    for plane in data.planes:
+        if plane.name == trace_reduce.HOST_PLANE:
+            spans += [(e.start_ns, e.end_ns, e.name,
+                       dict(e.stats).get("first_round"))
+                      for line in plane.lines for e in line.events
+                      if e.name.startswith(SPAN_PREFIX)]
+            continue
+        if not plane.name.startswith(trace_reduce.DEVICE_PREFIX):
+            continue
+        lines = {line.name: list(line.events) for line in plane.lines}
+        ops = lines.get(trace_reduce.OPS_LINE, [])
+        if ops:
+            chips.append((ops, lines.get(trace_reduce.MODULES_LINE, [])))
+    spans.sort()
+    per_chip = [_chip(ops, modules, scopes, spans, window_s)
+                for ops, modules in chips]
+    n = max(len(per_chip), 1)
+
+    def mean(key):
+        return sum(c[key] for c in per_chip) / n
+
+    phase_s = {}
+    for c in per_chip:
+        for scope, sec in c["phase_s"].items():
+            phase_s[scope] = phase_s.get(scope, 0.0) + sec / n
+    idle_rows = [sum(c["idle_rows"][k] for c in per_chip) / n
+                 for k in range(len(spans))]
+    span_idle = {}
+    for (_, _, name, _), idle in zip(spans, idle_rows):
+        span_idle[name] = span_idle.get(name, 0.0) + idle
     return Spans(
         window_s=window_s,
-        busy_s=sum(busy) / len(busy) if busy else 0.0,
-        module_s=sum(e - s for s, e in rounds) / 1e9,
-        module_idle_s=sum(idle.between(s, e) for s, e in rounds) / 1e9,
+        module_s=mean("module_s"),
+        module_idle_s=mean("module_idle_s"),
         phase_s=phase_s,
-        unscoped_s=unscoped,
-        control_s=(trace_reduce._union_s(spanned)
-                   - trace_reduce._union_s(leaves)),
-        idle_s=idle.total_ns / 1e9,
+        unscoped_s=mean("unscoped_s"),
+        control_s=mean("control_s"),
+        idle_s=mean("idle_s"),
         span_idle_s=span_idle,
-        unattributed_idle_s=(idle.total_ns - covered) / 1e9,
-        span_rows=rows,
-        module_runs_s=[(e - s) / 1e9 for s, e in rounds],
+        unattributed_idle_s=mean("unattributed_idle_s"),
+        span_rows=[[name, first_round, (e - s) / 1e9, idle]
+                   for (s, e, name, first_round), idle
+                   in zip(spans, idle_rows)],
+        module_runs_s=[r for c in per_chip for r in c["module_runs_s"]],
+        chips=len(per_chip),
     )
 
 
 _reduce_once = functools.lru_cache(maxsize=1)(reduce)
 
 
-def capture():
-    """The newest capture under the temporary directories bench/run.py
-    profiles into, or None."""
-    found = glob.glob(os.path.join(tempfile.gettempdir(), CAPTURES))
-    return max(found, key=os.path.getmtime) if found else None
-
-
 def for_ctx(ctx):
-    """The reduction of the traced window ``ctx`` describes, or None: an
-    untraced run, or no capture whose busy time the harness read."""
-    red = ctx.reduction
-    path = capture() if red is not None else None
-    if path is None:
+    """The reduction of the traced window ``ctx`` describes, or None in
+    an untraced run."""
+    if ctx.capture is None:
         return None
-    spans = _reduce_once(path, ctx.window_s)
-    if abs(spans.busy_s - red.busy_s) > 1e-9 * max(red.busy_s, 1.0):
-        return None
-    return spans
+    return _reduce_once(str(ctx.capture), ctx.window_s)
 
 
 def phase_ms(ctx, *scopes):
-    """Per-round device ms of the round program's ops under ``scopes``,
-    or None where the capture holds no phase."""
+    """Per-round, per-chip device ms of the round program's ops under
+    ``scopes``, or None where the capture holds no phase."""
     spans = for_ctx(ctx)
     if spans is None or not spans.phase_s:
         return None
@@ -299,7 +306,7 @@ def phase_ms(ctx, *scopes):
 
 
 def idle_ms(ctx, span=None):
-    """Per-round idle ms of the chip inside the ``span`` spans (inside no
+    """Per-round, per-chip idle ms inside the ``span`` spans (inside no
     ``train.*`` span for None), or None where the capture holds no
     span."""
     spans = for_ctx(ctx)

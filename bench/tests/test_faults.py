@@ -37,11 +37,13 @@ def compile_cache(tmp_path_factory):
     jax.config.update("jax_persistent_cache_min_compile_time_secs", old[1])
 
 
-def _spec():
+def _spec(**solver):
     bench = json.loads((ROOT / "BENCHMARK.json").read_text())
+    config = json.loads((DATA / "tiny.json").read_text())
+    config["solver"].update(solver)
     return {
         "cell": {"name": "tiny", "chips": 1},
-        "config": json.loads((DATA / "tiny.json").read_text()),
+        "config": config,
         "mix": json.loads((DATA / "tiny_mix.json").read_text()),
         "limits": json.loads(
             (ROOT / "bench" / "limits" / "qwen3-0.6b.train.json").read_text()),
@@ -82,15 +84,23 @@ def _no_exchange(monkeypatch):
                         jax.tree.map(jnp.zeros_like, tree))
 
 
-@pytest.mark.parametrize("fault,correct", [
-    (None, True),
-    (_unchanged, False),
-    (_half_batch, False),
-    (_no_exchange, False),
-], ids=["sound", "state_unchanged", "half_batch", "no_exchange"])
-def test_harness_decides_correct(monkeypatch, fault, correct):
+RING4 = {"agents": 4, "topology": "ring"}
+
+
+@pytest.mark.parametrize("fault,correct,solver", [
+    (None, True, {}),
+    (_unchanged, False, {}),
+    (_half_batch, False, {}),
+    (_no_exchange, False, {}),
+    (None, True, RING4),
+], ids=["sound", "state_unchanged", "half_batch", "no_exchange",
+        "sound_ring4"])
+def test_harness_decides_correct(monkeypatch, fault, correct, solver):
+    """``sound_ring4``: the system's host-simulated ring of four agents
+    (``--agents 4 --topology ring``) against the reference's ring."""
     if fault is not None:
         fault(monkeypatch)
-    result, checks = run.run_cell(_spec(), 2**31 + 5, 0.5, 0, jax.devices())
+    result, checks = run.run_cell(_spec(**solver), 2**31 + 5, 0.5, 0,
+                                  jax.devices())
     assert result["correct"] is correct, checks
     assert list(result)[-1] == "checks"

@@ -2,7 +2,6 @@
 readers on a capture with and without the program's spans."""
 import importlib
 import json
-import shutil
 import types
 from pathlib import Path
 
@@ -48,7 +47,7 @@ def test_phase_sums_and_unscoped_time_are_the_round_program(red):
     tr = trace_reduce.reduce(CAPTURE, META["window_s"])
     assert red.module_s == pytest.approx(tr.module_s["jit_run_chunk"],
                                          rel=1e-9)
-    assert red.busy_s == tr.busy_s
+    assert red.chips == tr.chips == 1
 
 
 def test_span_idles_and_unattributed_idle_are_the_idle_time(red):
@@ -58,7 +57,8 @@ def test_span_idles_and_unattributed_idle_are_the_idle_time(red):
     assert total == pytest.approx(red.idle_s, rel=1e-9)
     # device_idle_pct's idle: ops the capture holds past the host's
     # window end count as busy there, so the two differ by a hair
-    assert red.idle_s == pytest.approx(red.window_s - red.busy_s, rel=1e-3)
+    busy_s = trace_reduce.reduce(CAPTURE, META["window_s"]).busy_s
+    assert red.idle_s == pytest.approx(red.window_s - busy_s, rel=1e-3)
     rows = sum(idle for _, _, _, idle in red.span_rows)
     assert rows == pytest.approx(sum(red.span_idle_s.values()), rel=1e-9)
     assert all(0 <= idle <= wall for _, _, wall, idle in red.span_rows)
@@ -108,17 +108,12 @@ def test_trace_scopes_agree_with_round_scopes():
     assert set(traced.values()) == set(PHASES)
 
 
-def _ctx(tmp_path, monkeypatch, capture, window_s, rounds):
-    """The ``ctx`` bench/run.py hands a reader in a traced run, the
-    capture where its window profiles to."""
-    where = tmp_path / "bench.x" / "profile" / "plugins" / "profile" / "t"
-    where.mkdir(parents=True)
-    shutil.copy(capture, where / "host.xplane.pb")
-    monkeypatch.setattr(spans.tempfile, "tempdir", str(tmp_path))
+def _ctx(capture, window_s, rounds):
+    """The ``ctx`` bench/run.py hands a reader in a traced run."""
     spans._reduce_once.cache_clear()
-    return types.SimpleNamespace(
-        reduction=trace_reduce.reduce(capture, window_s),
-        window_s=window_s, rounds=rounds)
+    red = trace_reduce.reduce(capture, window_s)
+    return types.SimpleNamespace(reduction=red, capture=capture,
+                                 window_s=window_s, rounds=rounds)
 
 
 def _read(name, ctx):
@@ -129,9 +124,8 @@ READERS = ("local_device_ms", "plane_device_ms", "exchange_device_ms",
            "eval_idle_ms", "watchdog_idle_ms", "unattributed_idle_ms")
 
 
-def test_readers_on_the_recorded_window(tmp_path, monkeypatch, red):
-    ctx = _ctx(tmp_path, monkeypatch, CAPTURE, META["window_s"],
-               META["rounds"])
+def test_readers_on_the_recorded_window(red):
+    ctx = _ctx(CAPTURE, META["window_s"], META["rounds"])
     per_round = {name: _read(name, ctx) for name in READERS}
     ms = 1e3 / META["rounds"]
     assert per_round["local_device_ms"] == pytest.approx(
@@ -149,16 +143,106 @@ def test_readers_on_the_recorded_window(tmp_path, monkeypatch, red):
 
 
 @pytest.mark.parametrize("name", READERS)
-def test_readers_find_nothing_in_a_program_without_spans(
-        tmp_path, monkeypatch, name):
-    ctx = _ctx(tmp_path, monkeypatch, PARENT_CAPTURE, PARENT_WINDOW_S, 4)
+def test_readers_find_nothing_in_a_program_without_spans(name):
+    ctx = _ctx(PARENT_CAPTURE, PARENT_WINDOW_S, 4)
     assert _read(name, ctx) is None
 
 
-def test_readers_refuse_a_capture_of_another_window(tmp_path, monkeypatch):
-    ctx = _ctx(tmp_path, monkeypatch, CAPTURE, META["window_s"],
-               META["rounds"])
-    ctx.reduction = trace_reduce.reduce(PARENT_CAPTURE, PARENT_WINDOW_S)
-    assert spans.for_ctx(ctx) is None
-    ctx.reduction = None  # an untraced run
-    assert _read("local_device_ms", ctx) is None
+def test_readers_find_nothing_in_an_untraced_run():
+    ctx = _ctx(CAPTURE, META["window_s"], META["rounds"])
+    ctx.reduction = ctx.capture = None
+    assert all(_read(name, ctx) is None for name in READERS)
+
+
+# ---- several chips ----------------------------------------------------------
+
+
+def _varint(n):
+    out = bytearray()
+    while True:
+        out.append((n & 0x7F) | (0x80 if n > 0x7F else 0))
+        n >>= 7
+        if not n:
+            return bytes(out)
+
+
+def _field(number, body):
+    return _varint(number << 3 | 2) + _varint(len(body)) + body
+
+
+def four_chips(src, dst):
+    """``src`` with its one device plane given three copies, named
+    ``/device:TPU:1`` to ``3``: a capture of four chips that ran the
+    same ops at the same times."""
+    raw = memoryview(Path(src).read_bytes())
+    out, i = bytearray(raw), 0
+    while i < len(raw):
+        key, i = spans._varint(raw, i)
+        size, i = spans._varint(raw, i)
+        plane, i = raw[i:i + size], i + size
+        name = bytes(spans._first(plane, spans.XPLANE_NAME) or b"").decode()
+        if key >> 3 != spans.XSPACE_PLANES or name != "/device:TPU:0":
+            continue
+        # the plane's id and name, then the rest as it is
+        _, j = spans._varint(plane, 1)
+        name_size, j = spans._varint(plane, j + 1)
+        rest = bytes(plane[j + name_size:])
+        for chip in (1, 2, 3):
+            body = (_varint(1 << 3) + _varint(100 + chip)
+                    + _field(spans.XPLANE_NAME, f"/device:TPU:{chip}".encode())
+                    + rest)
+            out += _field(spans.XSPACE_PLANES, body)
+    Path(dst).write_bytes(bytes(out))
+    return dst
+
+
+PER_CHIP = READERS + ("round_device_ms", "loop_ms", "device_idle_pct",
+                      "step_mfu_pct")
+
+
+def test_four_chips_read_per_chip(tmp_path, red):
+    one = _ctx(CAPTURE, META["window_s"], META["rounds"])
+    four = _ctx(four_chips(CAPTURE, tmp_path / "four.xplane.pb"),
+                META["window_s"], META["rounds"])
+    # trace_reduce sums device seconds over the chips and averages busy
+    # time; the span reduction gives one chip's numbers
+    assert four.reduction.chips == 4
+    assert four.reduction.busy_s == one.reduction.busy_s
+    assert four.reduction.module_s["jit_run_chunk"] == pytest.approx(
+        4 * one.reduction.module_s["jit_run_chunk"], rel=1e-12)
+    red4 = spans.for_ctx(four)
+    assert red4.chips == 4
+    for key in ("module_s", "module_idle_s", "unscoped_s", "control_s",
+                "idle_s", "unattributed_idle_s"):
+        assert getattr(red4, key) == pytest.approx(getattr(red, key),
+                                                   rel=1e-12), key
+    assert red4.phase_s == pytest.approx(red.phase_s, rel=1e-12)
+    assert red4.span_idle_s == pytest.approx(red.span_idle_s, rel=1e-12)
+    assert [r[:3] for r in red4.span_rows] == [r[:3] for r in red.span_rows]
+    assert [r[3] for r in red4.span_rows] == pytest.approx(
+        [r[3] for r in red.span_rows], rel=1e-12)
+    assert all(0 <= idle <= wall for _, _, wall, idle in red4.span_rows)
+    assert len(red4.module_runs_s) == 4 * len(red.module_runs_s)
+    # the whole step's FLOPs over four chips' peak: 4x the work of one
+    # chip's agents reads as one chip's share
+    for ctx, agents in ((one, 2), (four, 8)):
+        ctx.model = json.loads((DATA / "tiny.json").read_text())
+        ctx.train = {"agents": agents, "m_local": 4, "tau": 2,
+                     "batch_size": 1, "seq_len": 32}
+        ctx.peaks = {"bf16_flops_per_s": 197e12}
+    for name in PER_CHIP:
+        assert _read(name, four) == pytest.approx(_read(name, one),
+                                                  rel=1e-12), name
+
+
+def test_memory_peak_is_the_fullest_chip():
+    from bench import run
+
+    def chip(stats):
+        return types.SimpleNamespace(memory_stats=lambda: stats)
+
+    chips = [chip({"peak_bytes_in_use": 5}), chip({"peak_bytes_in_use": 9}),
+             chip({}), chip(None)]
+    assert run.max_peak(chips) == 9
+    assert run.max_peak(chips[:1]) == 5
+    assert run.max_peak(chips[2:]) is None

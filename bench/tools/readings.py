@@ -3,7 +3,7 @@
     python bench/tools/readings.py WORKLOAD --seeds 101-112 --faults 3 \
         [--out readings.jsonl]
 
-One process, on the chip.  For every seed: the cell's checked call of
+One process, on the cell's chips.  For every seed: the cell's checked call of
 ``train.main`` (the rounds and log points ``bench/run.py`` checks) and
 the float32 reference, compared by ``bench/check.py``: the lower
 readings.  For the first ``--faults`` seeds also the control (the
@@ -56,13 +56,11 @@ def main(argv=None):
     ap.add_argument("--out", default=None)
     args = ap.parse_args(argv)
     sys.path[:0] = [str(ROOT / "src"), str(ROOT)]
-    import importlib
-
     import jax
     import jax.numpy as jnp
     import numpy as np
 
-    from bench import run
+    from bench import ref, run
     from bench.check import reference_as_program
     from bench.ref import ltadmm
     from repro.launch import compile_cache
@@ -70,11 +68,10 @@ def main(argv=None):
     compile_cache.enable()
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
     spec = run.load_cell(args.workload)
-    every = int(spec["mix"]["program"]["log-every"])
-    checked = every * math.ceil(run.CHECK_ROUNDS / every)
+    every, checked = run.checked_rounds(spec)
     train = run.reference_train(spec)
-    net = importlib.import_module(
-        "bench.ref." + Path(spec["config"]["reference"]).stem)
+    net = ref.module(spec["config"])
+    chips = jax.devices()[:int(spec["cell"]["chips"])]
     sink = open(args.out, "a") if args.out else None
 
     def emit(rec):
@@ -85,11 +82,12 @@ def main(argv=None):
             sink.flush()
 
     def reference(seed, **kw):
-        ref = ltadmm.Reference(spec["config"], train, net=net, **kw)
+        plain = ltadmm.Reference(spec["config"], train, net=net,
+                                 devices=chips, **kw)
         t0 = time.perf_counter()
         prec = "highest" if "plane_dtype" not in kw else "default"
         with jax.default_matmul_precision(prec):
-            out = ref.run(seed, checked, every)
+            out = plain.run(seed, checked, every)
         return out, time.perf_counter() - t0
 
     try:

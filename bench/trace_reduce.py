@@ -87,19 +87,32 @@ def _merged(intervals):
     return out
 
 
-def _label(host_events, g0, g1) -> str:
-    """Innermost host call covering at least half of ``[g0, g1]``, else
-    the one that overlaps it most."""
-    best, best_key = "host", None
-    span = max(g1 - g0, 1)
-    for name, s, e in host_events:
-        ov = min(e, g1) - max(s, g0)
-        if ov <= 0:
-            continue
-        key = (ov >= span / 2, -(e - s) if ov >= span / 2 else ov)
-        if best_key is None or key > best_key:
-            best, best_key = name, key
-    return best
+def _labels(host_events, gaps) -> list:
+    """The label of each of ``gaps`` (``(g0, g1)``, sorted and disjoint):
+    the innermost host event covering at least half of the gap, else the
+    one that overlaps it most; of equals, the first in ``host_events``.
+    One sweep over the events sorted by start."""
+    order = sorted(range(len(host_events)), key=lambda k: host_events[k][1])
+    nxt, live, out = 0, [], []
+    for g0, g1 in gaps:
+        while nxt < len(order) and host_events[order[nxt]][1] < g1:
+            live.append(order[nxt])
+            nxt += 1
+        # an event that ends before this gap ends before every later one
+        live = [k for k in live if host_events[k][2] > g0]
+        best, best_key = "host", None
+        span = max(g1 - g0, 1)
+        for k in live:
+            name, s, e = host_events[k]
+            ov = min(e, g1) - max(s, g0)
+            if ov <= 0:
+                continue
+            half = ov >= span / 2
+            key = (half, -(e - s) if half else ov, -k)
+            if best_key is None or key > best_key:
+                best, best_key = name, key
+        out.append(best)
+    return out
 
 
 def reduce(path, window_s: float, top: int = 10) -> Reduction:
@@ -139,8 +152,10 @@ def reduce(path, window_s: float, top: int = 10) -> Reduction:
         end_ns = window_s * 1e9
         for s, e in _merged(first_busy) + [[end_ns, end_ns]]:
             if s > edge:
-                gaps.append((_label(host_events, edge, s), (s - edge) / 1e9))
+                gaps.append((edge, s))
             edge = max(edge, e)
+        gaps = [(label, (g1 - g0) / 1e9) for label, (g0, g1)
+                in zip(_labels(host_events, gaps), gaps)]
         gaps.sort(key=lambda g: -g[1])
     return Reduction(
         window_s=window_s,
